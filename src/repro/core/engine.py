@@ -224,7 +224,7 @@ class BlinkDB:
         self._latency: dict[tuple[str, tuple[str, ...]], elp_lib.LatencyModel] = {}
         self._programs: dict = {}     # (table, phi, template) -> compiled fn
         self._batched_programs: dict = {}   # (scan key, Q_padded) -> compiled fn
-        self._quantile_programs: dict = {}  # (table, phi, template) -> jitted fn
+        self._quantile_programs: dict = {}  # (table, phi, template) -> compiled fn
         # (table, phi, value_col) -> (lo, hi) histogram range for the fused
         # one-pass quantile kernel; invalidated with the family's programs.
         self._quantile_ranges: dict = {}
@@ -570,8 +570,7 @@ class BlinkDB:
             if striped is not None:
                 upd = exec_lib.stripe_append(striped, merged, block)
                 if upd is None:   # outgrew padding: full compacting restripe
-                    self._striped[key] = exec_lib.stripe_family(
-                        merged, self._n_shards())
+                    self._striped[key] = self._stripe(merged)
                     self._drop_programs(table_name, phi)
                     restriped.append(phi)
                 else:
@@ -670,8 +669,7 @@ class BlinkDB:
         if striped is None:
             return False   # nothing materialized: next stripe is compact
         fam = self.families[table_name][phi]
-        fresh = exec_lib.stripe_family(fam, self._n_shards(),
-                                       min_local=striped.n_local)
+        fresh = self._stripe(fam, min_local=striped.n_local)
         self._striped[key] = fresh
         if fresh.shape_class != striped.shape_class:
             self._drop_programs(table_name, phi)
@@ -780,8 +778,7 @@ class BlinkDB:
         key = (table_name, phi)
         striped = self._striped.get(key)
         if striped is not None:
-            fresh = exec_lib.stripe_family(new_fam, self._n_shards(),
-                                           min_local=striped.n_local)
+            fresh = self._stripe(new_fam, min_local=striped.n_local)
             self._striped[key] = fresh
             if fresh.shape_class != striped.shape_class:
                 self._drop_programs(table_name, phi)
@@ -799,8 +796,13 @@ class BlinkDB:
         key = (table_name, phi)
         if key not in self._striped:
             fam = self.families[table_name][phi]
-            self._striped[key] = exec_lib.stripe_family(fam, self._n_shards())
+            self._striped[key] = self._stripe(fam)
         return self._striped[key]
+
+    def _stripe(self, fam: samp_lib.SampleFamily,
+                min_local: int | None = None) -> exec_lib.StripedFamily:
+        return exec_lib.stripe_family(fam, self._n_shards(), min_local,
+                                      mesh=self.mesh, data_axes=self.data_axes)
 
     def _encode(self, table_name: str):
         tbl = self.tables[table_name]
@@ -1109,7 +1111,7 @@ class BlinkDB:
                                           tuple[jax.Array, jax.Array]]:
         """ONE streaming pass producing BOTH the grouped moments and the
         histogram quantile (value, density) — no second full-column read.
-        The program is jitted and cached per (family × template × shape
+        The program is AOT-compiled and cached per (family × template × shape
         class); k, the predicate constants, the level, the histogram range,
         AND the striped block are traced args, so every re-instantiation
         (and every ELP probe) reuses one compiled program, including across
@@ -1121,17 +1123,19 @@ class BlinkDB:
         n_groups = self._column_card(table_name, group_col) if group_col else 1
         key = (table_name, phi, struct, q.value_column, group_col, n_groups,
                striped.shape_class)
+        lo, hi = self._family_range(table_name, phi, q.value_column)
+        args = (jnp.float32(k), vals, jnp.float32(q.quantile),
+                jnp.float32(lo), jnp.float32(hi),
+                *exec_lib.scan_args(striped))
         fn = self._quantile_programs.get(key)
         if fn is None:
-            fn = exec_lib.make_quantile_fn(struct, q.value_column, group_col,
-                                           n_groups, mesh=self.mesh,
-                                           data_axes=self.data_axes,
-                                           use_pallas=self.config.use_pallas)
+            jfn = exec_lib.make_quantile_fn(struct, q.value_column, group_col,
+                                            n_groups, mesh=self.mesh,
+                                            data_axes=self.data_axes,
+                                            use_pallas=self.config.use_pallas)
+            fn = jfn.lower(*args).compile()  # AOT, like the other scans
             self._quantile_programs[key] = fn
-        lo, hi = self._family_range(table_name, phi, q.value_column)
-        mom, qv, dens = fn(jnp.float32(k), vals, jnp.float32(q.quantile),
-                           jnp.float32(lo), jnp.float32(hi),
-                           *exec_lib.scan_args(striped))
+        mom, qv, dens = fn(*args)
         return mom, (qv, dens)
 
     def _run_quantile_at_k(self, table_name: str, q: Query,

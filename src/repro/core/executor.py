@@ -54,11 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.5
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core import estimators as est_lib
 from repro.core.sampling import SampleFamily
 from repro.core.types import (AggOp, Atom, CmpOp, Conjunction, Predicate,
@@ -249,8 +244,17 @@ def _fits_dtype(a, dtype) -> bool:
     return bool(a.min() >= info.min and a.max() <= info.max)
 
 
+def _replicated(striped: StripedFamily):
+    """Placement for small per-epoch payloads (scatter indices, delta rows,
+    the freq table): a full copy on every device of the block's mesh, or the
+    default device when the block is not sharded."""
+    sh = striped.unit.sharding
+    return NamedSharding(sh.mesh, P()) if isinstance(sh, NamedSharding) else None
+
+
 def stripe_family(fam: SampleFamily, n_shards: int,
-                  min_local: int | None = None) -> StripedFamily:
+                  min_local: int | None = None, *, mesh: Mesh | None = None,
+                  data_axes: tuple[str, ...] = ("data",)) -> StripedFamily:
     """Stripe on host, then move the WHOLE padded block with one device_put.
 
     Pad+reshape stays in NumPy (no per-column host→device round trips); the
@@ -263,6 +267,11 @@ def stripe_family(fam: SampleFamily, n_shards: int,
     n_local so the rebuilt block keeps the same shape class and every
     AOT-compiled program stays valid — the family only ever shrinks under
     compaction, so the old geometry always fits.
+
+    With a `mesh`, row s of every [S, n_local] array is placed on the device
+    that owns shard s of `data_axes` (the layout the shard_map scans take),
+    and the freq table is replicated; without one the block goes to the
+    default device.
     """
     n = fam.n_rows
     n_local = _padded_local(n, n_shards)
@@ -309,7 +318,13 @@ def stripe_family(fam: SampleFamily, n_shards: int,
         "freq_table": _padded_freq_table(
             fam.stratum_freqs.astype(np.float32)),
     }
-    dev = jax.device_put(host_block)
+    placement = None
+    if mesh is not None:
+        rows = NamedSharding(mesh, P(data_axes))
+        placement = {"cols": {c: rows for c in host_block["cols"]},
+                     "valid": rows, "unit": rows, "strat": rows,
+                     "freq_table": NamedSharding(mesh, P())}
+    dev = jax.device_put(host_block, placement)
     slot_row_ids = (fam.row_ids.astype(np.int64).copy()
                     if fam.row_ids is not None
                     else np.full(n, -1, dtype=np.int64))
@@ -378,7 +393,7 @@ def stripe_append(striped: StripedFamily, fam: SampleFamily,
     if d == 0:
         cols, unit, strat, valid = (striped.columns, striped.unit,
                                     striped.strat, striped.valid)
-        ftab = jax.device_put(freq_table)
+        ftab = jax.device_put(freq_table, _replicated(striped))
     else:
         # Narrow-dtype overflow: a delta value (or new stratum id) outside
         # the stored int8/int16 range cannot be scattered losslessly.
@@ -402,7 +417,7 @@ def stripe_append(striped: StripedFamily, fam: SampleFamily,
         }
         cols, unit, strat, valid, ftab = _scatter_refresh(
             striped.columns, striped.unit, striped.strat, striped.valid,
-            jax.device_put(payload))
+            jax.device_put(payload, _replicated(striped)))
     old_ids = (striped.slot_row_ids if striped.slot_row_ids is not None
                else np.full(start, -1, dtype=np.int64))
     new_ids = (block.row_ids.astype(np.int64) if block.row_ids is not None
@@ -454,7 +469,8 @@ def stripe_tombstone(striped: StripedFamily, dead_row_ids: np.ndarray,
     s_idx = (slots_p % striped.n_shards).astype(np.int32)
     l_idx = (slots_p // striped.n_shards).astype(np.int32)
     unit, valid = _scatter_ghost(striped.unit, striped.valid,
-                                 *jax.device_put((s_idx, l_idx)))
+                                 *jax.device_put((s_idx, l_idx),
+                                                 _replicated(striped)))
     new_ids = ids.copy()
     new_ids[slots] = -1
     return dataclasses.replace(
@@ -517,7 +533,7 @@ def run_query_striped(striped: StripedFamily, bound_pred, value_col: str | None,
         return jax.tree.map(lambda x: x.sum(axis=0), mom)
 
     pspec = P(data_axes)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda c, u, s, ft, v: _merge_psum(
             jax.tree.map(lambda x: x[0],
                          jax.vmap(lambda cc, uu, ss, vv: shard_fn(
@@ -526,6 +542,7 @@ def run_query_striped(striped: StripedFamily, bound_pred, value_col: str | None,
         mesh=mesh,
         in_specs=(pspec, pspec, pspec, P(), pspec),
         out_specs=P(),
+        check_vma=not use_pallas,
     )
     return fn(*scan_args(striped))
 
@@ -641,7 +658,7 @@ def make_query_fn(struct, value_col: str | None,
     pspec = P(data_axes)
 
     def fn(k, pred_vals, cols, unit, strat, freq_table, valid):
-        inner = _shard_map(
+        inner = jax.shard_map(
             lambda c, u, s, ft, v: _merge_psum(
                 jax.tree.map(lambda x: x[0],
                              jax.vmap(lambda cc, uu, ss, vv: shard_fn(
@@ -650,6 +667,9 @@ def make_query_fn(struct, value_col: str | None,
             mesh=mesh,
             in_specs=(pspec, pspec, pspec, P(), pspec),
             out_specs=P(),
+            # pallas_call outputs carry no varying-axes annotation; the
+            # psum above is what makes the result replicated.
+            check_vma=not use_pallas,
         )
         return inner(cols, unit, strat, freq_table, valid)
     return jax.jit(fn)
@@ -730,9 +750,9 @@ def make_batched_query_fn(struct,
             # statistics tensor instead of seven per-leaf reductions.
             merged = jax.lax.psum(jnp.stack(leaves), data_axes)
             return jax.tree.unflatten(treedef, list(merged))
-        inner = _shard_map(per_shard, mesh=mesh,
-                           in_specs=(pspec, pspec, pspec, P(), pspec),
-                           out_specs=P())
+        inner = jax.shard_map(per_shard, mesh=mesh,
+                              in_specs=(pspec, pspec, pspec, P(), pspec),
+                              out_specs=P(), check_vma=not use_pallas)
         return inner(cols, unit, strat, freq_table, valid)
     return jax.jit(fn)
 
@@ -797,7 +817,7 @@ def make_subsampled_query_fn(struct, value_col: str | None,
     pspec = P(data_axes)
 
     def fn(k, pred_vals, sub, cols, unit, strat, freq_table, valid):
-        inner = _shard_map(
+        inner = jax.shard_map(
             lambda sb, c, u, s, ft, v: _merge_psum(
                 jax.tree.map(lambda x: x[0],
                              jax.vmap(lambda sbb, cc, uu, ss, vv: shard_fn(
@@ -858,9 +878,10 @@ def make_batched_subsampled_query_fn(struct, value_col: str | None,
             leaves, treedef = jax.tree.flatten(mom)
             merged = jax.lax.psum(jnp.stack(leaves), data_axes)
             return jax.tree.unflatten(treedef, list(merged))
-        inner = _shard_map(per_shard, mesh=mesh,
-                           in_specs=(pspec, pspec, pspec, pspec, P(), pspec),
-                           out_specs=P())
+        inner = jax.shard_map(per_shard, mesh=mesh,
+                              in_specs=(pspec, pspec, pspec, pspec, P(),
+                                        pspec),
+                              out_specs=P())
         return inner(sub, cols, unit, strat, freq_table, valid)
     return jax.jit(fn)
 

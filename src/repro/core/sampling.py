@@ -265,8 +265,10 @@ def base_units(n: int, seed: int, *, uniform: bool = False) -> np.ndarray:
     The uniform family salts the seed so R(p) and SFam(φ) draw independently
     (matches the original build_family / build_uniform_family streams)."""
     key = jax.random.PRNGKey((seed ^ 0x5EED) if uniform else seed)
-    return np.asarray(jax.random.uniform(key, (n,), dtype=jnp.float32,
-                                         minval=1e-7, maxval=1.0))
+    # np.array copies: np.asarray of a device array is a read-only view,
+    # and callers such as the mutation oracle write into the units.
+    return np.array(jax.random.uniform(key, (n,), dtype=jnp.float32,
+                                       minval=1e-7, maxval=1.0))
 
 
 def delta_units(n: int, seed: int, epoch: int, *,

@@ -415,7 +415,7 @@ def from_columns(name: str, raw: Mapping[str, np.ndarray],
             raise ValueError(f"column {cname}: length {len(values)} != {n_rows}")
         is_cat = cname in categorical or not np.issubdtype(values.dtype, np.floating)
         if is_cat:
-            uniq, codes = np.unique(values, return_inverse=True)
+            uniq, codes = _unique_inverse(values)
             schemas.append(ColumnSchema(cname, ColumnKind.CATEGORICAL, len(uniq)))
             hosts[cname] = codes.astype(np.int32)
             cols[cname] = jnp.asarray(hosts[cname])
@@ -440,9 +440,73 @@ def combined_codes(table: Table, phi: Sequence[str]) -> tuple[np.ndarray, np.nda
     if not phi:
         n = table.n_rows
         return np.zeros(n, dtype=np.int64), np.zeros((1, 0), dtype=np.int32)
-    mats = np.stack([table.host_column(c) for c in phi], axis=1)
+    cols = [table.host_column(c) for c in phi]
+    fast = _row_ids(cols)
+    if fast is not None:
+        return fast
+    mats = np.stack(cols, axis=1)
     uniq, inverse = np.unique(mats, axis=0, return_inverse=True)
     return inverse.astype(np.int64), uniq.astype(np.int32)
+
+
+def _row_ids(cols: list[np.ndarray]
+             ) -> tuple[np.ndarray, np.ndarray] | None:
+    """np.unique(rows, axis=0, return_inverse=True) without its structured
+    row sort, for rows of non-negative integer columns: returns (int64
+    inverse, int32 distinct rows), or None when a column is not such a code
+    column or the key below would overflow int64.
+
+    One mixed-radix int64 key per row (first column most significant)
+    orders rows exactly as np.unique's lexicographic comparison does."""
+    if not len(cols[0]) or any(c.dtype.kind not in "iu" for c in cols):
+        return None
+    if any(int(c.min()) < 0 for c in cols):
+        return None
+    radix = [int(c.max()) + 1 for c in cols]
+    if np.prod(radix, dtype=float) >= 2.0 ** 62:
+        return None
+    key = np.zeros(len(cols[0]), dtype=np.int64)
+    for c, r in zip(cols, radix):
+        key = key * r + c
+    space = int(np.prod(radix))
+    if space <= max(1 << 22, 2 * len(key)):
+        seen = np.flatnonzero(np.bincount(key, minlength=space))
+        lut = np.empty(space, dtype=np.int64)
+        lut[seen] = np.arange(len(seen))
+        inverse = lut[key]
+    else:
+        seen, inverse = np.unique(key, return_inverse=True)
+    uniq = np.empty((len(seen), len(cols)), dtype=np.int32)
+    rest = seen
+    for j in range(len(cols) - 1, -1, -1):
+        rest, uniq[:, j] = np.divmod(rest, radix[j])
+    return inverse.astype(np.int64), uniq
+
+
+def _unique_inverse(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """np.unique(values, return_inverse=True) for a categorical column,
+    without a comparison sort where the values allow it: non-negative ints
+    directly, and fixed-width strings as rows of per-position code-point
+    ranks (NumPy orders str by code point, a shorter string first)."""
+    cols = None
+    if values.dtype.kind in "iu":
+        cols = [values]
+    elif values.dtype.kind == "U" and values.size:
+        width = values.dtype.itemsize // 4
+        cps = np.ascontiguousarray(values).view(np.uint32).reshape(-1, width)
+        cols = []
+        for j in range(width):
+            seen = np.bincount(cps[:, j]) > 0
+            if seen.sum() > 1:   # a constant position orders nothing
+                rank = (np.cumsum(seen) - 1).astype(np.int32)
+                cols.append(rank[cps[:, j]])
+    fast = _row_ids(cols) if cols else None
+    if fast is None:
+        return np.unique(values, return_inverse=True)
+    inverse, uniq_rows = fast
+    first = np.empty(len(uniq_rows), dtype=np.int64)
+    first[inverse[::-1]] = np.arange(len(values) - 1, -1, -1)
+    return values[first], inverse
 
 
 def stratum_frequencies(codes: np.ndarray, n_distinct: int) -> np.ndarray:

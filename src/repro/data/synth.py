@@ -68,9 +68,15 @@ def lineitem_table(n_rows: int = 200_000, seed: int = 1) -> dict[str, np.ndarray
 
 
 def _label(prefix: str, codes: np.ndarray) -> np.ndarray:
-    """Decode int codes to string labels (exercises dictionary encoding)."""
-    width = len(str(codes.max() if codes.size else 0))
-    return np.array([f"{prefix}{c:0{width}d}" for c in codes])
+    """Decode int codes to string labels (exercises dictionary encoding).
+    Formats each distinct label once and gathers by code, so the cost is a
+    NumPy take rather than one Python format per row."""
+    if not codes.size:
+        return np.array([])
+    width = len(str(codes.max()))
+    labels = np.array([f"{prefix}{c:0{width}d}"
+                       for c in range(int(codes.max()) + 1)])
+    return labels[codes]
 
 
 def _normalize(p: np.ndarray) -> np.ndarray:

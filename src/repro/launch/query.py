@@ -14,6 +14,7 @@ from repro.core import (AggOp, Atom, BlinkDB, CmpOp, EngineConfig, ErrorBound,
                         Predicate, Query, QueryTemplate, TimeBound)
 from repro.core import table as table_lib
 from repro.data import synth
+from repro.launch import compile_cache
 from repro.obs.clock import now_s
 
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--pallas", action="store_true",
                     help="use the Pallas fused scan (interpret mode on CPU)")
     args = ap.parse_args()
+    print(f"compile cache: {compile_cache.enable()}")
 
     t0 = now_s()
     tbl = table_lib.from_columns("sessions", synth.sessions_table(args.rows))

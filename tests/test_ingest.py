@@ -21,6 +21,42 @@ from repro.data import synth
 
 # ------------------------------------------------------------- table layer
 
+_ENCODE_CASES = {
+    "mixed_len_unicode": np.array(["b", "a", "ab", "", "abc", "é", "a", "zz"]),
+    "empty_str": np.array([], dtype="<U3"),
+    "constant_str": np.array(["x", "x"]),
+    "negative_ints": np.random.default_rng(1).integers(-5, 5, 100),
+    "wide_ints": np.random.default_rng(2).integers(0, 10**12, 100),
+    "int8": np.random.default_rng(3).integers(0, 7, 1000).astype(np.int8),
+    "bools": np.array([True, False, True]),
+    "labels": synth.sessions_table(20_000, seed=3)["City"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ENCODE_CASES))
+def test_dictionary_encoding_matches_np_unique(case):
+    """from_columns' sort-free encoder numbers values exactly as
+    np.unique(return_inverse=True) does — dictionary order, dtype, codes."""
+    values = _ENCODE_CASES[case]
+    want_dict, want_codes = np.unique(values, return_inverse=True)
+    got_dict, got_codes = table_lib._unique_inverse(values)
+    assert got_dict.dtype == want_dict.dtype
+    assert got_dict.tolist() == want_dict.tolist()
+    np.testing.assert_array_equal(got_codes, want_codes)
+
+
+@pytest.mark.parametrize("phi", [("City",), ("OS", "URL"),
+                                 ("City", "URL", "dt"), ("Bitrate",)])
+def test_combined_codes_match_np_unique_rows(phi):
+    tbl = table_lib.from_columns("s", synth.sessions_table(30_000, seed=2))
+    mats = np.stack([tbl.host_column(c) for c in sorted(phi)], axis=1)
+    want_rows, want_ids = np.unique(mats, axis=0, return_inverse=True)
+    got_ids, got_rows = table_lib.combined_codes(tbl, phi)
+    np.testing.assert_array_equal(got_ids, want_ids.astype(np.int64))
+    np.testing.assert_array_equal(got_rows, want_rows.astype(np.int32))
+    assert got_ids.dtype == np.int64 and got_rows.dtype == np.int32
+
+
 def test_append_extends_dictionaries_without_recoding():
     tbl = table_lib.from_columns("t", {
         "key": np.array(["b", "a", "b"]), "x": np.array([1., 2., 3.],
