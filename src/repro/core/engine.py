@@ -36,15 +36,14 @@ from repro.obs import trace as obs_trace
 from repro.sharding import placement as place_lib
 
 
-def _scan_stream_bytes(striped: "exec_lib.StripedFamily") -> int:
-    """Bytes/row the fused scan streams from HBM (trace attribute only —
-    computed lazily when a trace is active). Delegates to the roofline's
-    dtype-exact accounting; streamed blocks are the scan_args tail minus the
-    VMEM-resident freq table."""
-    from repro.launch import roofline
-    return roofline.scan_bytes_per_row(
-        [a.dtype for a in striped.columns.values()]
-        + [striped.unit.dtype, striped.strat.dtype, striped.valid.dtype])
+def _launch_and_wait(fn, *args):
+    """Run a compiled scan program inside the caller's scan span: the call
+    (argument handling, allocation, enqueue) as `scan.launch`, the wait
+    for every output as `scan.wait`."""
+    with obs_trace.span("scan.launch"):
+        out = fn(*args)
+    with obs_trace.span("scan.wait"):
+        return jax.tree.map(lambda x: x.block_until_ready(), out)
 
 
 @dataclasses.dataclass
@@ -194,6 +193,10 @@ class BlinkDB:
             "ErrorBound plans resolved to exact base-table scans")
         self._m_scan_seconds = self.metrics.histogram(
             "engine_scan_seconds", "Device scan wall time per fused pass")
+        self._m_compiles = self.metrics.counter(
+            "engine_program_compiles_total",
+            "Scan programs AOT-compiled (or loaded from the persistent "
+            "cache), by program kind", labels=("program",))
         self._m_shards_lost = self.metrics.counter(
             "engine_shards_lost_total",
             "Logical shards lost (no surviving replica) across scans")
@@ -960,6 +963,14 @@ class BlinkDB:
                 "dead_bytes": rb * (tbl.n_rows - tbl.n_live)
                 + sample_rb * ghost_rows}
 
+    def _compile(self, program: str, jfn, *args):
+        """AOT-compile `jfn` for `args` (no execution) in a `compile` span,
+        counted in `engine_program_compiles_total{program}`."""
+        with obs_trace.span("compile", program=program):
+            fn = jfn.lower(*args).compile()
+        self._m_compiles.labels(program).inc()
+        return fn
+
     def _run_at_k(self, table_name: str, q: Query, phi: tuple[str, ...],
                   k: float) -> tuple[est_lib.GroupedMoments, int, float,
                                      "exec_lib.ShardScanReport | None"]:
@@ -990,22 +1001,18 @@ class BlinkDB:
                 use_pallas=self.config.use_pallas)
             # AOT-compile (no execution) so the cold path runs the query
             # exactly once: the timed call below both warms and answers.
-            fn = jfn.lower(jnp.float32(k), vals, *args).compile()
+            fn = self._compile("query", jfn, jnp.float32(k), vals, *args)
             self._programs[key] = fn
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, k=float(k)) as sp:
-            if obs_trace.tracing_active():
-                sp.set(bytes_per_row=_scan_stream_bytes(striped))
-                self._set_placement_attrs(sp, table_name, phi, fam,
-                                          struct, [vals])
             t0 = time.perf_counter()
             report = None
             if self._fault_sharding_active():
                 def call(mask):
-                    m = fn(jnp.float32(k), vals, striped.columns,
-                           striped.unit, striped.strat, striped.freq_table,
-                           mask)
-                    return jax.tree.map(lambda x: x.block_until_ready(), m)
+                    return _launch_and_wait(
+                        fn, jnp.float32(k), vals, striped.columns,
+                        striped.unit, striped.strat, striped.freq_table,
+                        mask)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1014,15 +1021,17 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = fn(jnp.float32(k), vals, *args)
-                mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
+                mom = _launch_and_wait(fn, jnp.float32(k), vals, *args)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
-            sp.set(rows_read=rows, elapsed_s=dt)
+            sp.set(rows_read=rows)
             if report is not None:
                 sp.set(shards=report.n_shards, lost=list(report.lost),
                        rerouted=list(report.rerouted),
                        reweight=report.reweight)
+        if obs_trace.tracing_active():
+            self._set_placement_attrs(sp, table_name, phi, fam, struct,
+                                      [vals])
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(rows)
         self._count_shard_report(report)
@@ -1045,12 +1054,13 @@ class BlinkDB:
                 est = self._estimate_for(q, table_name, phi, k, mom, qpair)
             stderr, lo, hi = est_lib.ci(est, confidence)
         group_col = q.group_by[0] if q.group_by else None
-        vals = np.asarray(est.value)
-        errs = np.asarray(stderr)
-        los, his = np.asarray(lo), np.asarray(hi)
-        ns = np.asarray(est.n)
-        wsum = np.asarray(mom.wsum)
-        nsel = np.asarray(mom.n)
+        with obs_trace.span("fetch"):
+            vals = np.asarray(est.value)
+            errs = np.asarray(stderr)
+            los, his = np.asarray(lo), np.asarray(hi)
+            ns = np.asarray(est.n)
+            wsum = np.asarray(mom.wsum)
+            nsel = np.asarray(mom.n)
         groups = []
         realized_half = 0.0   # worst realized CI half-width, bound units
         for g in range(len(vals)):
@@ -1106,16 +1116,16 @@ class BlinkDB:
             self._quantile_ranges[key] = rng
         return rng
 
-    def _quantile_scan(self, q: Query, table_name: str, phi: tuple[str, ...],
-                       k: float) -> tuple[est_lib.GroupedMoments,
-                                          tuple[jax.Array, jax.Array]]:
-        """ONE streaming pass producing BOTH the grouped moments and the
-        histogram quantile (value, density) — no second full-column read.
-        The program is AOT-compiled and cached per (family × template × shape
-        class); k, the predicate constants, the level, the histogram range,
-        AND the striped block are traced args, so every re-instantiation
-        (and every ELP probe) reuses one compiled program, including across
-        incremental appends."""
+    def _quantile_program(self, q: Query, table_name: str,
+                          phi: tuple[str, ...], k: float) -> tuple:
+        """The fused QUANTILE program and its arguments: `fn(*args)` is ONE
+        streaming pass producing BOTH the grouped moments and the histogram
+        quantile (value, density) — no second full-column read. The program
+        is AOT-compiled and cached per (family × template × shape class); k,
+        the predicate constants, the level, the histogram range, AND the
+        striped block are traced args, so every re-instantiation (and every
+        ELP probe) reuses one compiled program, including across incremental
+        appends."""
         striped = self._striped_for(table_name, phi)
         bound_pred = exec_lib.bind_predicate(q.predicate, self._encode(table_name))
         struct, vals = exec_lib.pred_structure(bound_pred)
@@ -1133,10 +1143,9 @@ class BlinkDB:
                                             n_groups, mesh=self.mesh,
                                             data_axes=self.data_axes,
                                             use_pallas=self.config.use_pallas)
-            fn = jfn.lower(*args).compile()  # AOT, like the other scans
+            fn = self._compile("quantile", jfn, *args)
             self._quantile_programs[key] = fn
-        mom, qv, dens = fn(*args)
-        return mom, (qv, dens)
+        return fn, args
 
     def _run_quantile_at_k(self, table_name: str, q: Query,
                            phi: tuple[str, ...], k: float):
@@ -1145,18 +1154,18 @@ class BlinkDB:
         this off the fault-sharded path (per-shard moment partials need the
         plain scan program); timed like _run_at_k."""
         fam = self.families[table_name][phi]
+        fn, args = self._quantile_program(q, table_name, phi, k)
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, k=float(k),
                             quantile=True) as sp:
             t0 = time.perf_counter()
-            mom, qpair = self._quantile_scan(q, table_name, phi, k)
-            mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
+            mom, qv, dens = _launch_and_wait(fn, *args)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
-            sp.set(rows_read=rows, elapsed_s=dt)
+            sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(rows)
-        return mom, rows, dt, None, qpair
+        return mom, rows, dt, None, (qv, dens)
 
     def _scan_for_query(self, table_name: str, q: Query,
                         phi: tuple[str, ...], k: float):
@@ -1180,7 +1189,9 @@ class BlinkDB:
         if q.agg is not AggOp.QUANTILE:
             return est_lib.estimate(q.agg, mom)
         if qpair is None:
-            _, qpair = self._quantile_scan(q, table_name, phi, k)
+            fn, args = self._quantile_program(q, table_name, phi, k)
+            _, qv, dens = fn(*args)
+            qpair = (qv, dens)
         return est_lib.estimate(AggOp.QUANTILE, mom, quantile_value=qpair[0],
                                 quantile_density=qpair[1], q=q.quantile)
 
@@ -1231,8 +1242,6 @@ class BlinkDB:
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, k=float(k),
                             subsampled=True) as sp:
-            if obs_trace.tracing_active():
-                sp.set(bytes_per_row=_scan_stream_bytes(striped))
             t0 = time.perf_counter()
             if q.agg is AggOp.QUANTILE:
                 fn = self._subsampled_quantile_programs.get(key)
@@ -1241,11 +1250,9 @@ class BlinkDB:
                         struct, q.value_column, group_col, n_groups, b,
                         mesh=self.mesh, data_axes=self.data_axes)
                     self._subsampled_quantile_programs[key] = fn
-                mom_sub, qv, dens, qsub = fn(jnp.float32(k), vals,
-                                             jnp.float32(q.quantile), sub,
-                                             *args)
-                mom_sub = jax.tree.map(lambda x: x.block_until_ready(),
-                                       mom_sub)
+                mom_sub, qv, dens, qsub = _launch_and_wait(
+                    fn, jnp.float32(k), vals, jnp.float32(q.quantile), sub,
+                    *args)
                 est = est_lib.subsampling_estimate(
                     AggOp.QUANTILE, mom_sub, n_groups, b, quantile_value=qv,
                     quantile_density=dens, quantile_values_sub=qsub,
@@ -1257,13 +1264,12 @@ class BlinkDB:
                         struct, q.value_column, group_col, n_groups, b,
                         mesh=self.mesh, data_axes=self.data_axes)
                     self._subsampled_programs[key] = fn
-                mom_sub = fn(jnp.float32(k), vals, sub, *args)
-                mom_sub = jax.tree.map(lambda x: x.block_until_ready(),
-                                       mom_sub)
+                mom_sub = _launch_and_wait(fn, jnp.float32(k), vals, sub,
+                                           *args)
                 est = est_lib.subsampling_estimate(q.agg, mom_sub, n_groups, b)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
-            sp.set(rows_read=rows, elapsed_s=dt)
+            sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(rows)
         mom = est_lib.fold_subsamples(mom_sub, n_groups, b)
@@ -1669,23 +1675,19 @@ class BlinkDB:
                 struct, value_col, group_col, n_groups,
                 mesh=self.mesh, data_axes=self.data_axes,
                 use_pallas=self.config.use_pallas)
-            fn = jfn.lower(ks_dev, consts_dev, *args).compile()  # AOT
+            fn = self._compile("batched", jfn, ks_dev, consts_dev, *args)
             self._batched_programs[pkey] = fn
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, batch=n_q,
                             k=float(max(ks))) as sp:
-            if obs_trace.tracing_active():
-                sp.set(bytes_per_row=_scan_stream_bytes(striped))
-                self._set_placement_attrs(
-                    sp, table_name, phi, self.families[table_name][phi],
-                    struct, consts_list, flat=True)
             t0 = time.perf_counter()
             report = None
             if self._fault_sharding_active():
                 def call(mask):
-                    m = fn(ks_dev, consts_dev, striped.columns, striped.unit,
-                           striped.strat, striped.freq_table, mask)
-                    return jax.tree.map(lambda x: x.block_until_ready(), m)
+                    return _launch_and_wait(
+                        fn, ks_dev, consts_dev, striped.columns,
+                        striped.unit, striped.strat, striped.freq_table,
+                        mask)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1694,18 +1696,22 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = fn(ks_dev, consts_dev, *args)
-                mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
+                mom = _launch_and_wait(fn, ks_dev, consts_dev, *args)
             dt = time.perf_counter() - t0
-            rows = self.families[table_name][phi].prefix_for_k(max(ks))
-            sp.set(rows_read=rows, elapsed_s=dt)
+            fam = self.families[table_name][phi]
+            rows = fam.prefix_for_k(max(ks))
+            sp.set(rows_read=rows)
             if report is not None:
                 sp.set(shards=report.n_shards, lost=list(report.lost),
                        rerouted=list(report.rerouted))
+        if obs_trace.tracing_active():
+            self._set_placement_attrs(sp, table_name, phi, fam, struct,
+                                      consts_list, flat=True)
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(rows)
         self._count_shard_report(report)
-        return jax.tree.map(lambda x: x[:n_q], mom), dt, report
+        with obs_trace.span("fetch"):
+            return jax.tree.map(lambda x: x[:n_q], mom), dt, report
 
     def _run_batched_subsampled(self, scan_key, ks: Sequence[float],
                                 consts_list: Sequence[tuple[float, ...]]
@@ -1746,22 +1752,21 @@ class BlinkDB:
             jfn = exec_lib.make_batched_subsampled_query_fn(
                 struct, value_col, group_col, n_groups, b,
                 mesh=self.mesh, data_axes=self.data_axes)
-            fn = jfn.lower(ks_dev, consts_dev, sub, *args).compile()  # AOT
+            fn = self._compile("subsampled", jfn, ks_dev, consts_dev, sub,
+                               *args)
             self._batched_subsampled_programs[pkey] = fn
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, batch=n_q,
                             k=float(max(ks)), subsampled=True) as sp:
-            if obs_trace.tracing_active():
-                sp.set(bytes_per_row=_scan_stream_bytes(striped))
             t0 = time.perf_counter()
-            mom = fn(ks_dev, consts_dev, sub, *args)
-            mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
+            mom = _launch_and_wait(fn, ks_dev, consts_dev, sub, *args)
             dt = time.perf_counter() - t0
             rows = self.families[table_name][phi].prefix_for_k(max(ks))
-            sp.set(rows_read=rows, elapsed_s=dt)
+            sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(rows)
-        return jax.tree.map(lambda x: x[:n_q], mom), dt, None
+        with obs_trace.span("fetch"):
+            return jax.tree.map(lambda x: x[:n_q], mom), dt, None
 
     def query_batch(self, queries: Sequence[Query],
                     deadline_headroom_s: float = 0.0) -> list[Answer]:
@@ -1857,21 +1862,26 @@ class BlinkDB:
                 dec = (self._cached_decision(job.elp_key, job.table)
                        if self.config.reuse_elp else None)
                 if dec is None:
-                    mi = est_lib.moments_slice(mom, i)
-                    est = (self._quantile_estimate(job.q, job.table,
-                                                   job.phi, k_probe, mi)
-                           if job.q.agg is AggOp.QUANTILE
-                           else est_lib.estimate(job.q.agg, mi))
-                    n_pilot = np.asarray(est.n, dtype=np.float64)
-                    infl = est_lib.pilot_inflation(n_pilot, job.confidence)
-                    n_req = np.asarray(est_lib.required_n_for_error(
-                        job.q.agg, est, job.q.bound.eps, job.confidence,
-                        job.q.bound.relative))
-                    k_q = elp_lib.pick_k_for_error(fam, n_pilot,
-                                                   n_req * infl, k_probe)
-                    k_half = k_q if k_q is not None else fam.ks[0]
-                    half = self._predicted_half(job.q, est, infl, k_probe,
-                                                k_half, job.confidence)
+                    # This job's slice of the shared pilot, brought to the
+                    # host numbers that size its K.
+                    with obs_trace.span("fetch"):
+                        mi = est_lib.moments_slice(mom, i)
+                        est = (self._quantile_estimate(job.q, job.table,
+                                                       job.phi, k_probe, mi)
+                               if job.q.agg is AggOp.QUANTILE
+                               else est_lib.estimate(job.q.agg, mi))
+                        n_pilot = np.asarray(est.n, dtype=np.float64)
+                        infl = est_lib.pilot_inflation(n_pilot,
+                                                       job.confidence)
+                        n_req = np.asarray(est_lib.required_n_for_error(
+                            job.q.agg, est, job.q.bound.eps, job.confidence,
+                            job.q.bound.relative))
+                        k_q = elp_lib.pick_k_for_error(fam, n_pilot,
+                                                       n_req * infl, k_probe)
+                        k_half = k_q if k_q is not None else fam.ks[0]
+                        half = self._predicted_half(job.q, est, infl,
+                                                    k_probe, k_half,
+                                                    job.confidence)
                     # The shared batched probe IS this job's pilot; only
                     # unreachable bounds walk the rest of the ladder.
                     dec = self._plan_error_bound(job.table, job.q, job.phi,
@@ -1903,15 +1913,17 @@ class BlinkDB:
             per_query_dt = dt / len(group)  # amortized shared-scan time
             for i, job in enumerate(group):
                 fam = self.families[job.table][job.phi]
-                mi = est_lib.moments_slice(mom, i)
+                with obs_trace.span("fetch"):
+                    mi = est_lib.moments_slice(mom, i)
+                    rows = fam.prefix_for_k(job.k)
                 est = None
                 if sub_mode:
                     est = est_lib.subsampling_estimate(job.q.agg, mi,
                                                        n_groups, b)
                     mi = est_lib.fold_subsamples(mi, n_groups, b)
                 ans = self._answer_from_moments(
-                    job.q, job.table, job.phi, job.k, mi,
-                    fam.prefix_for_k(job.k), per_query_dt, job.confidence,
+                    job.q, job.table, job.phi, job.k, mi, rows,
+                    per_query_dt, job.confidence,
                     faults=rep, certified=job.certified,
                     predicted_half_width=job.predicted_half, est=est)
                 sub_answers[job.parent].append((job.order, ans))
@@ -1965,36 +1977,43 @@ class BlinkDB:
                       else jnp.zeros(n_rows, jnp.int32))
                 return est_lib.grouped_moments(values_, ones_, disj, g_,
                                                n_groups)
-            fn = jax.jit(build).lower(vals, tcols, live).compile()  # AOT
+            fn = self._compile("exact", jax.jit(build), vals, tcols, live)
             self._exact_programs[key] = fn
 
         with obs_trace.span("scan.exact", table=q.table) as sp:
             t0 = time.perf_counter()
-            mom = fn(vals, tcols, live)
-            mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
-            if q.agg is AggOp.QUANTILE:
-                # Only the quantile pass needs the raw mask/values/groups —
-                # the compiled program above already evaluated the predicate
-                # for the moment statistics.
-                mask = exec_lib.predicate_mask(tcols, bound_pred) & live
-                values = (tcols[q.value_column].astype(jnp.float32)
-                          if q.value_column
-                          else jnp.ones(tbl.n_rows, jnp.float32))
-                g = (tcols[group_col].astype(jnp.int32) if group_col
-                     else jnp.zeros(tbl.n_rows, jnp.int32))
-                qv, dens = exec_lib.grouped_quantile(
-                    values, mask.astype(jnp.float32), g, n_groups, q.quantile)
-                est = est_lib.estimate(AggOp.QUANTILE, mom, quantile_value=qv,
-                                       quantile_density=dens, q=q.quantile)
-            else:
-                est = est_lib.estimate(q.agg, mom)
-            est.value.block_until_ready()
+            with obs_trace.span("scan.launch"):
+                mom = fn(vals, tcols, live)
+            # The eager QUANTILE pass and the estimate are host-dispatched
+            # device work that waits on the program's moments: scan.wait.
+            with obs_trace.span("scan.wait"):
+                mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
+                if q.agg is AggOp.QUANTILE:
+                    # Only the quantile pass needs the raw mask/values/
+                    # groups — the compiled program above already evaluated
+                    # the predicate for the moment statistics.
+                    mask = exec_lib.predicate_mask(tcols, bound_pred) & live
+                    values = (tcols[q.value_column].astype(jnp.float32)
+                              if q.value_column
+                              else jnp.ones(tbl.n_rows, jnp.float32))
+                    g = (tcols[group_col].astype(jnp.int32) if group_col
+                         else jnp.zeros(tbl.n_rows, jnp.int32))
+                    qv, dens = exec_lib.grouped_quantile(
+                        values, mask.astype(jnp.float32), g, n_groups,
+                        q.quantile)
+                    est = est_lib.estimate(
+                        AggOp.QUANTILE, mom, quantile_value=qv,
+                        quantile_density=dens, q=q.quantile)
+                else:
+                    est = est_lib.estimate(q.agg, mom)
+                est.value.block_until_ready()
             dt = time.perf_counter() - t0
-            sp.set(rows_read=tbl.n_rows, elapsed_s=dt)
+            sp.set(rows_read=tbl.n_rows)
         self._m_scan_seconds.observe(dt)
         self._m_rows_read.inc(tbl.n_rows)
-        vals = np.asarray(est.value)
-        ns = np.asarray(est.n)
+        with obs_trace.span("fetch"):
+            vals = np.asarray(est.value)
+            ns = np.asarray(est.n)
         groups = []
         for gidx in range(len(vals)):
             if ns[gidx] == 0:

@@ -17,12 +17,19 @@ Design constraints (docs/OBSERVABILITY.md):
   and cross-thread spans nest under the anchor span the activating side
   designated (`QueryTrace.set_anchor`).
 * **Monotonic.** All stamps come from `obs.clock.now_s`.
+* **Mirrored into the profiler.** A live span also enters a
+  `jax.profiler.TraceAnnotation` named `blinkql.<span name>`, so under
+  `jax.profiler.trace` the serving stages sit on the profiler's host
+  plane beside the program launches they make, on the device trace's own
+  clock. Backfilled spans
+  (stamped after the fact, like `parse` and `admit`) are not mirrored.
 
 The span taxonomy the serving path emits is cataloged in
 docs/OBSERVABILITY.md; tests/test_obs.py asserts ladder completeness.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from collections import deque
 from dataclasses import dataclass, field
@@ -174,9 +181,18 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+@functools.cache
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use: code that
+    never records a span never loads the profiler."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
 class _Span:
-    """Live span recording into every trace active on this thread."""
-    __slots__ = ("_recs",)
+    """Live span recording into every trace active on this thread, and
+    mirrored into the profiler's trace as `blinkql.<name>`."""
+    __slots__ = ("_recs", "_mirror")
 
     def __init__(self, traces: tuple[QueryTrace, ...], name: str,
                  attrs: dict[str, Any]):
@@ -184,6 +200,7 @@ class _Span:
         # worth the aliasing risk: .set() must reach all of them anyway).
         self._recs = [(tr, tr.open_span(name, dict(attrs)))
                       for tr in traces]
+        self._mirror = _trace_annotation()(f"blinkql.{name}")
 
     def set(self, **attrs) -> "_Span":
         for _, rec in self._recs:
@@ -191,9 +208,11 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        self._mirror.__enter__()
         return self
 
     def __exit__(self, etype, evalue, tb) -> bool:
+        self._mirror.__exit__(etype, evalue, tb)
         if etype is not None:
             for _, rec in self._recs:
                 rec.attrs.setdefault("error", etype.__name__)
@@ -205,7 +224,9 @@ class _Span:
 def span(name: str, **attrs):
     """Open a span on every trace active on this thread; a cheap no-op
     (thread-local read + singleton) when none is. Usable as a context
-    manager; `.set(**attrs)` adds attributes discovered mid-span."""
+    manager; `.set(**attrs)` adds attributes discovered mid-span, or after
+    it closed (attributes that cost work are computed outside the timed
+    interval and set then)."""
     active = getattr(_TLS, "active", None)
     if not active:
         return _NULL

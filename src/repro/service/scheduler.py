@@ -146,6 +146,7 @@ class _Request:
     query: Query                    # normalized (cache/workload key)
     done: threading.Event
     t_submit: float
+    t_popped: float | None = None   # dispatcher took it off the queue
     answer: Answer | None = None
     error: BaseException | None = None
     future: Future | None = None    # submit_async/submit_many completion
@@ -761,6 +762,13 @@ class BlinkQLService:
                                r.t_submit + 0.5 * r.query.bound.seconds)
         return deadline
 
+    def _pop(self) -> _Request:
+        """Take the oldest queued request, stamping when it left the queue
+        (the end of its `admit.queue` span). Caller holds `_cond`."""
+        r = self._queue.popleft()
+        r.t_popped = time.monotonic()
+        return r
+
     def _collect_batch(self) -> list[_Request]:
         """Block until requests are pending, then drain for up to one
         batching window (or max_batch / deadline pressure)."""
@@ -770,11 +778,11 @@ class BlinkQLService:
                 self._cond.wait()
             if not self._queue:
                 return []
-            batch = [self._queue.popleft()]
+            batch = [self._pop()]
             t_first = batch[0].t_submit
             while len(batch) < self.config.max_batch:
                 while self._queue and len(batch) < self.config.max_batch:
-                    batch.append(self._queue.popleft())
+                    batch.append(self._pop())
                 if len(batch) >= self.config.max_batch or self._stop:
                     break
                 remaining = self._flush_deadline(batch, t_first) \
@@ -866,11 +874,17 @@ class BlinkQLService:
         t_exec = time.monotonic()
         for r in batch:
             if r.trace is not None:
-                # Backfill the queue wait (admission → this execution slot):
-                # t_submit is the same monotonic clock spans use.
+                # Backfill the wait (admission → this execution slot) and,
+                # inside it, the part spent queued until the dispatcher
+                # popped the request; the rest of `admit` is the batching
+                # window's hold. t_submit is the same monotonic clock spans
+                # use.
                 rec = r.trace.open_span("admit", {"batch": len(batch)})
+                queued = r.trace.open_span("admit.queue", {})
+                r.trace.close_span(queued)
                 r.trace.close_span(rec)
                 rec.t0, rec.t1 = r.t_submit, t_exec
+                queued.t0, queued.t1 = r.t_submit, r.t_popped
         traces = [r.trace for r in batch if r.trace is not None]
         try:
             # The shared call activates EVERY sampled trace in the batch:

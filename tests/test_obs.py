@@ -466,3 +466,255 @@ def test_fault_injection_counter_in_merged_snapshot(db):
 def _fault_count(snap) -> float:
     vals = snap["counters"].get("fault_injections_total", {})
     return sum(vals.get("values", {}).values())
+
+
+# ================================== spans inside the scan and the queue
+
+UNBOUNDED_TXT = ("SELECT AVG(SessionTime) FROM sessions "
+                 "WHERE City = 'city003'")
+QUANTILE_TXT = ("SELECT QUANTILE(SessionTime, 0.5) FROM sessions "
+                "WHERE City = 'city005'")
+# Span names recorded before the scan split; any other span of these
+# stages must sit under a span of its own stage, so Answer.timings and the
+# benchmark's stage readers see the same intervals as before.
+STAGES_READ = ("plan", "scan", "estimate")
+PRE_SPLIT = {"plan", "plan.pilot", "plan.certify_top", "plan.escalate",
+             "scan", "scan.shard", "scan.exact", "estimate"}
+TAXONOMY = PRE_SPLIT | {"request", "parse", "cache", "admit", "admit.queue",
+                        "scan.launch", "scan.wait", "fetch", "compile",
+                        "ladder.retry", "ladder.stale_serve",
+                        "ladder.exhausted"}
+
+
+@pytest.fixture(scope="module")
+def sub_db():
+    tbl = table_lib.from_columns("sessions",
+                                 synth.sessions_table(20_000, seed=2))
+    d = BlinkDB(EngineConfig(k1=400.0, m=3, seed=1, ci_method="subsampling",
+                             n_subsamples=8))
+    d.register_table("sessions", tbl)
+    d.add_family("sessions", ("City",))
+    d.add_family("sessions", ())
+    return d
+
+
+def _q(db, text):
+    return parse_blinkql(text, db).normalized()
+
+
+def _traced(run) -> QueryTrace:
+    tr = QueryTrace("q", reason="forced")
+    with activate(tr):
+        run()
+    tr.finish()
+    return tr
+
+
+def _ancestors(tr, s):
+    p = s.parent
+    while p >= 0:
+        yield tr.spans[p]
+        p = tr.spans[p].parent
+
+
+def _assert_scans_split(tr):
+    """Each program run under a scan span is split into its launch and its
+    wait, in that order; attempts a fault plan killed ran no program."""
+    runs = [s for s in tr.spans if s.name in ("scan", "scan.exact")]
+    assert runs
+    for sc in runs:
+        kids = tr.children(sc.index)
+        if any(k.name == "scan.shard" for k in kids):
+            runs += [k for k in kids if k.attrs.get("ok")]
+            continue
+        assert [k.name for k in kids] == ["scan.launch", "scan.wait"], \
+            (sc.name, [k.name for k in kids])
+        launch, wait = kids
+        assert sc.t0 <= launch.t0 <= launch.t1 <= wait.t0 <= wait.t1 <= sc.t1
+    for s in tr.spans:
+        if s.name in ("scan.launch", "scan.wait"):
+            assert tr.spans[s.parent].name in ("scan", "scan.exact",
+                                                "scan.shard")
+
+
+def _kill_one_replica():
+    return arm(FaultPlan([FaultSpec(site="shard.scan", kind="kill",
+                                    match=(("shard", 1), ("replica", 0)))],
+                         seed=0))
+
+
+def _run_path(path, db, sub_db):
+    q = _q(db, AVG_TXT)
+    if path == "single":
+        return _traced(lambda: db.query(_q(db, UNBOUNDED_TXT)))
+    if path == "batched":
+        return _traced(lambda: db.query_batch([q, _q(db, UNBOUNDED_TXT)]))
+    if path == "subsampled":
+        return _traced(lambda: (
+            sub_db.query(_q(sub_db, UNBOUNDED_TXT)),
+            sub_db.query_batch([_q(sub_db, UNBOUNDED_TXT)] * 2)))
+    if path == "quantile":
+        return _traced(lambda: db.query(_q(db, QUANTILE_TXT)))
+    if path == "exact":
+        return _traced(lambda: db.exact_query(_q(db, QUANTILE_TXT)))
+    assert path == "sharded"
+    with _kill_one_replica():
+        return _traced(lambda: db.query(q))
+
+
+@pytest.mark.parametrize("path", ["single", "batched", "subsampled",
+                                  "quantile", "exact", "sharded"])
+def test_every_scan_path_splits_launch_and_wait(path, db, sub_db):
+    tr = _run_path(path, db, sub_db)
+    _assert_scans_split(tr)
+    if path == "quantile":
+        assert any(s.attrs.get("quantile") for s in tr.find("scan"))
+    if path == "exact":
+        assert tr.find("scan.exact")
+    if path == "sharded":
+        attempts = tr.find("scan.shard")
+        assert len(attempts) == N_SHARDS + 1
+        failed = [s for s in attempts if s.attrs.get("ok") is False]
+        assert len(failed) == 1 and tr.children(failed[0].index) == []
+    for s in tr.find("scan") + tr.find("scan.exact"):
+        # Tracing's own attributes are set after the timed interval; the
+        # second clock and the unread byte count are gone.
+        assert "elapsed_s" not in s.attrs and "bytes_per_row" not in s.attrs
+        assert s.attrs["rows_read"] > 0
+
+
+def test_new_spans_keep_the_stage_readings(db):
+    """The rule for new spans: a span of a stage Answer.timings and the
+    benchmark's readers bill (plan, scan, estimate) either existed before
+    the scan split or nests under a span of its own stage; every other new
+    span takes a stage of its own. So each stage's outermost spans, and the
+    union the front-end reading subtracts, are what they were."""
+    svc = BlinkQLService(db, config=ServiceConfig(use_cache=False,
+                                                  solo_bypass=False,
+                                                  trace_sample_every=1))
+    texts = [AVG_TXT, UNBOUNDED_TXT, QUANTILE_TXT,
+             "SELECT COUNT(*) FROM sessions WHERE City = 'city001' "
+             "WITHIN 2 SECONDS",
+             "SELECT SUM(SessionTime) FROM sessions WHERE OS = 'os1' "
+             "ERROR WITHIN 3% CONFIDENCE 95%",
+             "SELECT AVG(SessionTime) FROM sessions WHERE City = 'city004' "
+             "ERROR WITHIN 0.0001% CONFIDENCE 95%"]
+    try:
+        answers = svc.submit_many(texts)
+        with _kill_one_replica():
+            answers.append(svc.submit(AVG_TXT))
+    finally:
+        svc.close()
+    seen = set()
+    for ans in answers:
+        tr = ans.trace
+        assert tr is not None
+        seen |= set(tr.span_names())
+        for s in tr.spans:
+            assert s.name in TAXONOMY, s.name
+            stage = s.name.split(".", 1)[0]
+            if stage in STAGES_READ and s.name not in PRE_SPLIT:
+                assert any(a.name.split(".", 1)[0] == stage
+                           for a in _ancestors(tr, s)), s.name
+        billed = [s for s in tr.spans
+                  if s.name.split(".", 1)[0] in STAGES_READ]
+        assert _union(billed) == _union(
+            [s for s in billed if s.name in PRE_SPLIT])
+    assert {"scan.launch", "scan.wait", "fetch", "admit.queue"} <= seen
+
+
+def _union(spans):
+    out = []
+    for t0, t1 in sorted((s.t0, s.t1) for s in spans):
+        if out and t0 <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], t1)
+        else:
+            out.append([t0, t1])
+    return out
+
+
+def test_admit_queue_nests_inside_admit(db):
+    svc = BlinkQLService(db, config=ServiceConfig(use_cache=False,
+                                                  solo_bypass=False))
+    try:
+        tr = svc.submit(AVG_TXT).trace
+    finally:
+        svc.close()
+    (admit,) = tr.find("admit")
+    (queued,) = tr.find("admit.queue")
+    assert queued.parent == admit.index
+    # From submission until the dispatcher popped it; the rest of admit is
+    # the batching window's hold.
+    assert admit.t0 == queued.t0 <= queued.t1 <= admit.t1
+
+
+def test_compile_spans_and_counter_once_per_program():
+    tbl = table_lib.from_columns("sessions",
+                                 synth.sessions_table(8_000, seed=4))
+    d = BlinkDB(EngineConfig(k1=200.0, m=2, seed=3))
+    d.register_table("sessions", tbl)
+    d.add_family("sessions", ("City",))
+    compiles = d.metrics.counter("engine_program_compiles_total",
+                                 labels=("program",))
+
+    def run():
+        d.query(_q(d, UNBOUNDED_TXT))
+        d.query(_q(d, QUANTILE_TXT))
+        d.query_batch([_q(d, UNBOUNDED_TXT)] * 2)
+        d.exact_query(_q(d, UNBOUNDED_TXT))
+
+    first = _traced(run)
+    kinds = [s.attrs["program"] for s in first.find("compile")]
+    assert set(kinds) == {"query", "quantile", "batched", "exact"}
+    for kind in set(kinds):
+        assert compiles.value(kind) == kinds.count(kind)
+    again = _traced(run)                 # every program is reused
+    assert again.find("compile") == []
+    for kind in set(kinds):
+        assert compiles.value(kind) == kinds.count(kind)
+    assert "engine_program_compiles_total" in d.metrics.snapshot()["counters"]
+
+
+def test_live_spans_mirror_into_the_profiler_trace(db, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    q = _q(db, UNBOUNDED_TXT)
+    db.query(q)                          # compile before the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    tr = QueryTrace("q", reason="forced")
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        with activate(tr):
+            db.query(q)
+    tr.finish()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events: dict[str, list[float]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("blinkql."):
+                    events.setdefault(ev.name, []).append(ev.start_ns)
+    offsets = []
+    for name in ("scan.launch", "scan.wait"):
+        spans = sorted(tr.find(name), key=lambda s: s.t0)
+        starts = sorted(events.get(f"blinkql.{name}", []))
+        assert len(starts) == len(spans) >= 1
+        offsets += [e - s.t0 * 1e9 for e, s in zip(starts, spans)]
+    # One clock offset maps every span onto its host event.
+    assert max(offsets) - min(offsets) < 1e6
+
+
+def test_span_without_trace_never_touches_the_profiler(monkeypatch):
+    from repro.obs import trace as obs_trace
+
+    def no_profiler():
+        raise AssertionError("the profiler was reached with no trace")
+    monkeypatch.setattr(obs_trace, "_trace_annotation", no_profiler)
+    assert active_traces() == ()
+    sp = span("scan.wait")
+    assert sp is obs_trace._NULL
+    with sp:
+        pass
