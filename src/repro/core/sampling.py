@@ -220,7 +220,7 @@ class SampleFamily:
         if ek is None:
             ek = np.asarray(self.entry_key)
             self.entry_key_host = ek
-        return int(np.searchsorted(ek, k, side="left"))
+        return count_below(ek, k)
 
     def rate(self, k: float) -> jax.Array:
         """Per-row inclusion probability at resolution k (HT weights = 1/rate)."""
@@ -248,6 +248,23 @@ class DeltaBlock:
     @property
     def n_rows(self) -> int:
         return int(self.unit.size)
+
+
+def count_below(ek: np.ndarray, k: float) -> int:
+    """Entries of the sorted key array `ek` strictly below `k`, compared in
+    float64 — the same integer as `np.searchsorted(ek.astype(np.float64), k)`.
+
+    The search runs in ek's own dtype: a float64 key against a float32 array
+    makes NumPy copy the whole array to float64 on every call, which on a
+    10M-row family costs more than the scan the count is for. For x in ek's
+    dtype, x < k exactly when x < k rounded UP to that dtype, so the key is
+    rounded to nearest and stepped up one ulp if that fell below k. A k above
+    the dtype's range rounds to inf, which still counts every finite entry."""
+    with np.errstate(over="ignore"):
+        key = ek.dtype.type(k)
+    if float(key) < k:
+        key = np.nextafter(key, ek.dtype.type(np.inf))
+    return int(np.searchsorted(ek, key, side="left"))
 
 
 def resolution_caps(k1: float, c: float, m: int) -> tuple[float, ...]:
@@ -321,7 +338,7 @@ def _assemble_family(phi: tuple[str, ...], ks: tuple[float, ...],
     order = np.argsort(entry_key[keep], kind="stable")
     idx = np.nonzero(keep)[0][order]
     ek = entry_key[idx]
-    prefixes = tuple(int(np.searchsorted(ek, k, side="left")) for k in ks)
+    prefixes = tuple(count_below(ek, k) for k in ks)
     cols_host = {name: np.asarray(arr)[idx] for name, arr in host_cols.items()}
     unit_host = units.astype(np.float32)[idx]
     return SampleFamily(
@@ -495,8 +512,7 @@ def merge_family(fam: SampleFamily, delta_columns: Mapping[str, np.ndarray],
     ek_m = np.concatenate([old_ek[keep_old], block.entry_key])
     order = np.argsort(ek_m, kind="stable")
     ek_sorted = ek_m[order]
-    prefixes = tuple(int(np.searchsorted(ek_sorted, k, side="left"))
-                     for k in ks)
+    prefixes = tuple(count_below(ek_sorted, k) for k in ks)
 
     def merge_col(old_arr, new_arr):
         old_h = np.asarray(old_arr)[keep_old]
@@ -591,7 +607,7 @@ def apply_tombstones(fam: SampleFamily, row_ids: np.ndarray,
     unit_host = (fam.unit_host if fam.unit_host is not None
                  else np.asarray(fam.unit))[keep]
     row_strata = fam.row_strata[keep]
-    prefixes = tuple(int(np.searchsorted(ek, k, side="left")) for k in fam.ks)
+    prefixes = tuple(count_below(ek, k) for k in fam.ks)
     out = SampleFamily(
         phi=fam.phi, ks=fam.ks,
         columns=None, freq=None, entry_key=None, unit=None,  # lazy mirrors
@@ -693,8 +709,7 @@ def decay_strata(fam: SampleFamily, tbl: table_lib.Table,
     ek_m = np.concatenate([fam.entry_key_host[keep_old], ek_new[keep_new]])
     order = np.argsort(ek_m, kind="stable")
     ek_sorted = ek_m[order]
-    prefixes = tuple(int(np.searchsorted(ek_sorted, k, side="left"))
-                     for k in fam.ks)
+    prefixes = tuple(count_below(ek_sorted, k) for k in fam.ks)
 
     def merge_col(old_arr, new_arr):
         old_h = np.asarray(old_arr)[keep_old]

@@ -122,3 +122,59 @@ def test_family_properties_c_bound(sessions):
             (k_opt, k_chosen, rows_opt, rows_chosen)
         # error ratio: sd ∝ 1/sqrt(n_selected) ⇒ ratio ≤ sqrt(c) (+slack)
         assert np.sqrt(rows_chosen / max(rows_opt, 1)) <= np.sqrt(c) + 0.35
+
+
+# Keys for the prefix search, each a function of the family and its mirror:
+# for every one the count must equal the float64 comparison `ek < k`.
+_PREFIX_KEYS = {
+    "ks1": lambda fam, ek: fam.ks[1],
+    "k1_third": lambda fam, ek: fam.ks[0] / 3,      # not a float32 value
+    "stored": lambda fam, ek: float(ek[len(ek) // 2]),
+    # one float64 ulp either side of a stored key: rounding the first to
+    # float32 lands ON the stored key, which must still count as below it
+    "stored_up": lambda fam, ek: float(np.nextafter(
+        np.float64(ek[len(ek) // 2]), np.inf)),
+    "stored_down": lambda fam, ek: float(np.nextafter(
+        np.float64(ek[len(ek) // 2]), 0.0)),
+    "zero": lambda fam, ek: 0.0,
+    "above_all": lambda fam, ek: 2.0 * float(ek[-1]) + 1.0,
+    "above_f32_range": lambda fam, ek: 1e40,
+}
+
+
+@pytest.fixture(scope="module")
+def keyed_family():
+    tbl = table_lib.from_columns("s", synth.sessions_table(8_000, seed=5))
+    return samp.build_family(tbl, ("City",), k1=1000.0, m=3, seed=1)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("key", sorted(_PREFIX_KEYS))
+def test_count_below_matches_float64_search(keyed_family, key, dtype):
+    ek = keyed_family.entry_key_host.astype(dtype)
+    fam = keyed_family.lazy_replace(entry_key_host=ek)
+    k = _PREFIX_KEYS[key](keyed_family, ek)
+    want = int(np.searchsorted(ek.astype(np.float64), k, side="left"))
+    with np.errstate(all="raise"):
+        assert samp.count_below(ek, k) == want
+        assert fam.prefix_for_k(k) == want
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_count_below_searches_in_mirror_dtype(keyed_family, monkeypatch,
+                                              dtype):
+    """A key of another dtype makes NumPy copy the whole mirror on every
+    search; what reaches searchsorted must already be the mirror's dtype."""
+    ek = keyed_family.entry_key_host.astype(dtype)
+    fam = keyed_family.lazy_replace(entry_key_host=ek)
+    seen = []
+    real = np.searchsorted
+
+    def spy(a, v, *args, **kwargs):
+        seen.append((a.dtype, np.asarray(v).dtype))
+        return real(a, v, *args, **kwargs)
+
+    monkeypatch.setattr(samp.np, "searchsorted", spy)
+    fam.prefix_for_k(keyed_family.ks[0] / 3)
+    samp.count_below(ek, 1e40)
+    assert seen == [(np.dtype(dtype), np.dtype(dtype))] * 2
