@@ -36,13 +36,20 @@ from repro.obs import trace as obs_trace
 from repro.sharding import placement as place_lib
 
 
-def _launch_and_wait(fn, *args):
+# Families of more strata than this count as wide in
+# engine_scanned_rows_total; the benchmark's wide-strata reader draws the
+# same line on the spans' `strata`.
+WIDE_STRATA = 4096
+
+
+def _launch_and_wait(fn, *args, strata: int):
     """Run a compiled scan program inside the caller's scan span: the call
     (argument handling, allocation, enqueue) as `scan.launch`, the wait
-    for every output as `scan.wait`."""
-    with obs_trace.span("scan.launch"):
+    for every output as `scan.wait`, each with the scanned family's
+    stratum count as `strata`."""
+    with obs_trace.span("scan.launch", strata=strata):
         out = fn(*args)
-    with obs_trace.span("scan.wait"):
+    with obs_trace.span("scan.wait", strata=strata):
         return jax.tree.map(lambda x: x.block_until_ready(), out)
 
 
@@ -185,6 +192,10 @@ class BlinkDB:
             labels=("path",))
         self._m_rows_read = self.metrics.counter(
             "engine_rows_read_total", "Sample/base rows scanned on device")
+        self._m_scanned_rows = self.metrics.counter(
+            "engine_scanned_rows_total",
+            "Rows scanned on device, by the family's stratum count: wide "
+            f"(more than {WIDE_STRATA} strata) or narrow", labels=("strata",))
         self._m_escalations = self.metrics.counter(
             "engine_k_escalations_total",
             "ErrorBound plans escalated past the selected family (§4.2)")
@@ -918,6 +929,12 @@ class BlinkDB:
         sp.set(placement=pl.span_attrs(),
                shard_set=("all" if route is None else list(route)))
 
+    def _count_rows(self, rows: int, strata: int) -> None:
+        """Rows one scan read, in total and by its family's width."""
+        self._m_rows_read.inc(rows)
+        self._m_scanned_rows.labels(
+            "wide" if strata > WIDE_STRATA else "narrow").inc(rows)
+
     def _count_shard_report(
             self, report: "exec_lib.ShardScanReport | None") -> None:
         if report is None:
@@ -1012,7 +1029,7 @@ class BlinkDB:
                     return _launch_and_wait(
                         fn, jnp.float32(k), vals, striped.columns,
                         striped.unit, striped.strat, striped.freq_table,
-                        mask)
+                        mask, strata=fam.n_distinct)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1021,7 +1038,8 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = _launch_and_wait(fn, jnp.float32(k), vals, *args)
+                mom = _launch_and_wait(fn, jnp.float32(k), vals, *args,
+                                       strata=fam.n_distinct)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
             sp.set(rows_read=rows)
@@ -1033,7 +1051,7 @@ class BlinkDB:
             self._set_placement_attrs(sp, table_name, phi, fam, struct,
                                       [vals])
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(rows)
+        self._count_rows(rows, fam.n_distinct)
         self._count_shard_report(report)
         return mom, rows, dt, report
 
@@ -1159,12 +1177,13 @@ class BlinkDB:
         with obs_trace.span("scan", table=table_name, k=float(k),
                             quantile=True) as sp:
             t0 = time.perf_counter()
-            mom, qv, dens = _launch_and_wait(fn, *args)
+            mom, qv, dens = _launch_and_wait(fn, *args,
+                                             strata=fam.n_distinct)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
             sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(rows)
+        self._count_rows(rows, fam.n_distinct)
         return mom, rows, dt, None, (qv, dens)
 
     def _scan_for_query(self, table_name: str, q: Query,
@@ -1252,7 +1271,7 @@ class BlinkDB:
                     self._subsampled_quantile_programs[key] = fn
                 mom_sub, qv, dens, qsub = _launch_and_wait(
                     fn, jnp.float32(k), vals, jnp.float32(q.quantile), sub,
-                    *args)
+                    *args, strata=fam.n_distinct)
                 est = est_lib.subsampling_estimate(
                     AggOp.QUANTILE, mom_sub, n_groups, b, quantile_value=qv,
                     quantile_density=dens, quantile_values_sub=qsub,
@@ -1265,13 +1284,13 @@ class BlinkDB:
                         mesh=self.mesh, data_axes=self.data_axes)
                     self._subsampled_programs[key] = fn
                 mom_sub = _launch_and_wait(fn, jnp.float32(k), vals, sub,
-                                           *args)
+                                           *args, strata=fam.n_distinct)
                 est = est_lib.subsampling_estimate(q.agg, mom_sub, n_groups, b)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
             sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(rows)
+        self._count_rows(rows, fam.n_distinct)
         mom = est_lib.fold_subsamples(mom_sub, n_groups, b)
         return self._answer_from_moments(
             q, table_name, phi, k, mom, rows, dt, confidence,
@@ -1647,6 +1666,7 @@ class BlinkDB:
         the batch — they shared the one scan that lost the shard."""
         table_name, phi, struct, value_col, group_col, n_groups = scan_key
         striped = self._striped_for(table_name, phi)
+        fam = self.families[table_name][phi]
         n_q = len(ks)
         if n_q > _MAX_SCAN_BATCH:
             moms, total_dt, reports = [], 0.0, []
@@ -1687,7 +1707,7 @@ class BlinkDB:
                     return _launch_and_wait(
                         fn, ks_dev, consts_dev, striped.columns,
                         striped.unit, striped.strat, striped.freq_table,
-                        mask)
+                        mask, strata=fam.n_distinct)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1696,9 +1716,9 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = _launch_and_wait(fn, ks_dev, consts_dev, *args)
+                mom = _launch_and_wait(fn, ks_dev, consts_dev, *args,
+                                       strata=fam.n_distinct)
             dt = time.perf_counter() - t0
-            fam = self.families[table_name][phi]
             rows = fam.prefix_for_k(max(ks))
             sp.set(rows_read=rows)
             if report is not None:
@@ -1708,7 +1728,7 @@ class BlinkDB:
             self._set_placement_attrs(sp, table_name, phi, fam, struct,
                                       consts_list, flat=True)
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(rows)
+        self._count_rows(rows, fam.n_distinct)
         self._count_shard_report(report)
         with obs_trace.span("fetch"):
             return jax.tree.map(lambda x: x[:n_q], mom), dt, report
@@ -1759,12 +1779,14 @@ class BlinkDB:
         with obs_trace.span("scan", table=table_name, batch=n_q,
                             k=float(max(ks)), subsampled=True) as sp:
             t0 = time.perf_counter()
-            mom = _launch_and_wait(fn, ks_dev, consts_dev, sub, *args)
+            fam = self.families[table_name][phi]
+            mom = _launch_and_wait(fn, ks_dev, consts_dev, sub, *args,
+                                   strata=fam.n_distinct)
             dt = time.perf_counter() - t0
-            rows = self.families[table_name][phi].prefix_for_k(max(ks))
+            rows = fam.prefix_for_k(max(ks))
             sp.set(rows_read=rows)
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(rows)
+        self._count_rows(rows, fam.n_distinct)
         with obs_trace.span("fetch"):
             return jax.tree.map(lambda x: x[:n_q], mom), dt, None
 
@@ -1982,11 +2004,12 @@ class BlinkDB:
 
         with obs_trace.span("scan.exact", table=q.table) as sp:
             t0 = time.perf_counter()
-            with obs_trace.span("scan.launch"):
+            # The base table is no family: strata 0.
+            with obs_trace.span("scan.launch", strata=0):
                 mom = fn(vals, tcols, live)
             # The eager QUANTILE pass and the estimate are host-dispatched
             # device work that waits on the program's moments: scan.wait.
-            with obs_trace.span("scan.wait"):
+            with obs_trace.span("scan.wait", strata=0):
                 mom = jax.tree.map(lambda x: x.block_until_ready(), mom)
                 if q.agg is AggOp.QUANTILE:
                     # Only the quantile pass needs the raw mask/values/
@@ -2010,7 +2033,7 @@ class BlinkDB:
             dt = time.perf_counter() - t0
             sp.set(rows_read=tbl.n_rows)
         self._m_scan_seconds.observe(dt)
-        self._m_rows_read.inc(tbl.n_rows)
+        self._count_rows(tbl.n_rows, 0)
         with obs_trace.span("fetch"):
             vals = np.asarray(est.value)
             ns = np.asarray(est.n)

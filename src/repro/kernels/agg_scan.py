@@ -48,14 +48,17 @@ f32/int32 copies of dictionary-encoded predicate/group columns. The fused
 kernel streams the minimum bytes per row instead:
 
 * **In-kernel HT derivation.** The stratum frequency table (padded to a
-  multiple of 128 lanes) rides along as a VMEM-resident constant block,
-  exactly like qconst. Per row block the kernel derives
-  `freq[1, B] = ftab[1, D] @ onehot(strat)[D, B]` with a statically
-  unrolled chunked one-hot matmul (each row of the onehot has exactly one
-  1.0, so the f32 dot is bit-identical to the gather `freq_table[strat]`),
-  then `entry_key = unit · freq` in VMEM. Only `unit` (f32) and `strat`
-  (narrow int) stream from HBM — ~8 fewer bytes/row than materialized
-  freq/entry_key, and append/tombstone paths stop rebuilding derived arrays.
+  multiple of 128 lanes, laid out as [D/128, 128]) rides along as a
+  VMEM-resident constant block, exactly like qconst. Per row block the
+  kernel derives freq with a two-level one-hot: `T[D/128, B] =
+  ftab @ onehot(strat % 128)[128, B]`, then row `strat // 128` of each
+  column of T (every one-hot column has exactly one 1.0, so the f32 dot
+  and the select are bit-identical to the gather `freq_table[strat]`),
+  then `entry_key = unit · freq` in VMEM. One matmul serves any table up
+  to MAX_FUSED_STRATA (every int16 stratum id). Only `unit` (f32) and
+  `strat` (narrow int) stream from HBM — ~8 fewer bytes/row than
+  materialized freq/entry_key, and append/tombstone paths stop rebuilding
+  derived arrays.
 * **Packed narrow dtypes.** Dictionary-encoded atom/group columns and
   `strat` stream at their natural width (int8/int16 chosen from dictionary
   size by the executor) and are widened to f32/int32 in VMEM. `valid` rides
@@ -85,7 +88,7 @@ DEFAULT_BLOCK_GROUPS = 512
 N_STATS = 8  # 7 used + 1 pad row for sublane alignment
 CONST_LANES = 128  # qconst lane width: 1 (k) + up to 127 predicate atoms
 FTAB_LANES = 128   # freq-table constant block is padded to this lane width
-MAX_FUSED_STRATA = 4096  # in-kernel derivation unrolls D/128 chunks; cap it
+MAX_FUSED_STRATA = 2 ** 15  # every id an int16 stratum column holds
 DEFAULT_QUANTILE_BINS = 256
 
 _CMP = cmp_fns()
@@ -296,27 +299,29 @@ def agg_scan_batched_pallas(values: jax.Array, freq: jax.Array,
 
 
 def _derive_freq(ftab_ref, strat_ref):
-    """freq[1, B] from the VMEM-resident frequency table.
+    """freq[1, B] from the VMEM-resident frequency table ftab[NH, 128]
+    (stratum s at row s // 128, lane s % 128).
 
-    Statically unrolled chunked one-hot matmul: for each 128-lane chunk of
-    the table, ftab_chunk[1, 128] @ (strat == chunk_ids)[128, B]. Each
-    column of the one-hot has exactly one 1.0 across ALL chunks, so every
-    per-row sum is ft[strat] plus exact zeros — bit-identical to the f32
-    gather `freq_table[strat]` regardless of accumulation order.
+    Two-level one-hot: T[NH, B] = ftab @ (s % 128 == lane)[128, B] holds
+    in column b every row's entry at lane s_b % 128; row s_b // 128 is then
+    selected on the VPU. Each one-hot column has exactly one 1.0, so at
+    full precision every entry of T is a table value plus exact zeros and
+    the select adds only zeros: bit-identical to the f32 gather
+    `freq_table[strat]`. A one-row table (at most 128 strata) is T itself.
     """
     s = strat_ref[...].astype(jnp.int32)[None, :]             # [1, B]
-    b = s.shape[1]
-    n_chunks = ftab_ref.shape[1] // FTAB_LANES
-    freq = jnp.zeros((1, b), jnp.float32)
-    for ci in range(n_chunks):
-        ids = ci * FTAB_LANES + jax.lax.broadcasted_iota(
-            jnp.int32, (FTAB_LANES, 1), 0)
-        onehot = (s == ids).astype(jnp.float32)               # [128, B]
-        chunk = ftab_ref[0, ci * FTAB_LANES:(ci + 1) * FTAB_LANES][None, :]
-        freq = freq + jax.lax.dot_general(
-            chunk, onehot, (((1,), (0,)), ((), ())),
-            precision=_EXACT, preferred_element_type=jnp.float32)
-    return freq
+    nh = ftab_ref.shape[0]
+    lane = s if nh == 1 else s & (FTAB_LANES - 1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, (FTAB_LANES, 1), 0)
+    onehot = (lane == ids).astype(jnp.float32)                # [128, B]
+    t = jax.lax.dot_general(                                  # [NH, B]
+        ftab_ref[...], onehot, (((1,), (0,)), ((), ())),
+        precision=_EXACT, preferred_element_type=jnp.float32)
+    if nh == 1:
+        return t
+    rows = jax.lax.broadcasted_iota(jnp.int32, (nh, 1), 0)
+    row = s >> (FTAB_LANES.bit_length() - 1)                 # s // 128
+    return jnp.sum(jnp.where(row == rows, t, 0.0), axis=0, keepdims=True)
 
 
 def _eval_dnf(qconst_ref, atom_refs, prefix, *, ops_struct, atom_slots,
@@ -385,14 +390,18 @@ def _fused_scan_kernel(qconst_ref, ftab_ref, values_ref, unit_ref, strat_ref,
 
 
 def _pad_ftab(freq_table: jax.Array) -> jax.Array:
-    """[1, D_pad] f32 constant block, D_pad a multiple of FTAB_LANES ≥ 128.
+    """[D_pad / 128, 128] f32 constant block, D_pad a multiple of
+    FTAB_LANES ≥ 128: stratum s at row s // 128, lane s % 128.
 
     Pad entries are 1.0 (never selected: strat < D), keeping rates finite."""
     d = freq_table.shape[0]
+    if d > MAX_FUSED_STRATA:
+        raise ValueError(f"freq table has {d} strata; max "
+                         f"{MAX_FUSED_STRATA} for in-kernel derivation")
     d_pad = max(FTAB_LANES, -(-d // FTAB_LANES) * FTAB_LANES)
     ft = jnp.pad(freq_table.astype(jnp.float32), (0, d_pad - d),
                  constant_values=1.0)
-    return ft[None, :]
+    return ft.reshape(-1, FTAB_LANES)
 
 
 def _normalize_atoms(atom_cols, ops_struct, atom_slots, n_rows):
@@ -441,9 +450,6 @@ def agg_scan_fused_pallas(values: jax.Array, unit: jax.Array,
     n_atoms = sum(len(c) for c in ops_struct)
     if n_atoms + 1 > CONST_LANES:
         raise ValueError(f"predicate has {n_atoms} atoms; max {CONST_LANES - 1}")
-    if freq_table.shape[0] > MAX_FUSED_STRATA:
-        raise ValueError(f"freq table has {freq_table.shape[0]} strata; "
-                         f"max {MAX_FUSED_STRATA} for in-kernel derivation")
     atom_cols, atom_slots = _normalize_atoms(atom_cols, ops_struct, atom_slots, n)
 
     q_pad = max(8, -(-q // 8) * 8)
@@ -486,7 +492,7 @@ def agg_scan_fused_pallas(values: jax.Array, unit: jax.Array,
         grid=(n_group_blocks, n_row_blocks),
         in_specs=[
             pl.BlockSpec((q_pad, CONST_LANES), lambda gi, ri: (0, 0)),
-            pl.BlockSpec((1, ftab.shape[1]), lambda gi, ri: (0, 0)),
+            pl.BlockSpec(ftab.shape, lambda gi, ri: (0, 0)),
             row_spec, row_spec, row_spec, row_spec, row_spec,
         ] + [row_spec] * len(acols),
         out_specs=pl.BlockSpec((N_STATS * q_pad, bg), lambda gi, ri: (0, gi)),
@@ -583,9 +589,6 @@ def quantile_scan_pallas(values: jax.Array, unit: jax.Array, strat: jax.Array,
     n_atoms = sum(len(c) for c in ops_struct)
     if n_atoms + 3 > CONST_LANES:
         raise ValueError(f"predicate has {n_atoms} atoms; max {CONST_LANES - 3}")
-    if freq_table.shape[0] > MAX_FUSED_STRATA:
-        raise ValueError(f"freq table has {freq_table.shape[0]} strata; "
-                         f"max {MAX_FUSED_STRATA} for in-kernel derivation")
     if n_bins % 128 != 0:
         raise ValueError(f"n_bins must be a multiple of 128, got {n_bins}")
     atom_cols, atom_slots = _normalize_atoms(atom_cols, ops_struct, atom_slots, n)
@@ -624,7 +627,7 @@ def quantile_scan_pallas(values: jax.Array, unit: jax.Array, strat: jax.Array,
         grid=(n_group_blocks, n_row_blocks),
         in_specs=[
             pl.BlockSpec((8, CONST_LANES), lambda gi, ri: (0, 0)),
-            pl.BlockSpec((1, ftab.shape[1]), lambda gi, ri: (0, 0)),
+            pl.BlockSpec(ftab.shape, lambda gi, ri: (0, 0)),
             row_spec, row_spec, row_spec, row_spec, row_spec,
         ] + [row_spec] * len(acols),
         out_specs=[

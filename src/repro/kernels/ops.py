@@ -50,7 +50,8 @@ def agg_scan_fused(values: jax.Array, unit: jax.Array, strat: jax.Array,
                    n_groups: int) -> GroupedMoments:
     """Memory-lean Q-query shared scan: streams the primitive striped layout
     (unit/strat/valid + narrow-dtype columns) and derives HT state in VMEM
-    from the resident freq table. Leaves are [Q, G]."""
+    from the resident freq table, for families of up to MAX_FUSED_STRATA
+    strata (every int16 stratum id). Leaves are [Q, G]."""
     out = agg_scan_fused_pallas(values, unit, strat, freq_table, valid,
                                 atom_cols, group_codes, ks, pred_consts,
                                 ops_struct=ops_struct, atom_slots=atom_slots,

@@ -4,7 +4,9 @@ Interpret-mode sweeps (the repo's substitute for hypothesis, which is not
 installed: seeded parametrized cases) covering the in-kernel HT derivation
 against ref.agg_scan_fused_ref, bit-identity with the pre-fusion batched
 kernel, narrow dtype widths, the 127-atom template limit, padding rows
-(entry_key=+inf), ghost slots, 1-stratum freq tables, the fused quantile
+(entry_key=+inf), ghost slots, 1-stratum freq tables, families of up to
+every int16 stratum id (and the executor's programs keeping them on the
+kernel), the fused quantile
 kernel, the grouped_quantile empty-selection guard, and the engine's
 one-pass QUANTILE execution (observable through its program caches).
 """
@@ -99,7 +101,7 @@ def test_fused_kernel_one_stratum():
 
 
 def test_fused_kernel_multichunk_freq_table():
-    """freq table wider than one 128-lane chunk (statically unrolled)."""
+    """freq table wider than one 128-lane row (the row select runs)."""
     rng = np.random.default_rng(15)
     args = _fused_case(rng, 4000, 10, q=3, n_atoms=1, n_strata=300,
                        strat_dtype=np.int16)
@@ -143,13 +145,88 @@ def test_fused_kernel_127_atom_limit():
                               interpret=True)
 
 
-def test_fused_kernel_strata_cap():
-    rng = np.random.default_rng(19)
-    v, u, s, ftab, va, atoms, c, ks, consts = _fused_case(rng, 256, 2, 1, 1)
+def _wide_case(n_strata, q):
+    """A family with `n_strata` strata (int16 ids, first and last present)
+    and stratum sizes up to 1e7, so a freq off by one ulp shows."""
+    rng = np.random.default_rng(n_strata)
+    n = 2 * n_strata + 1000
+    v, u, _, _, va, atoms, c, _, consts = _fused_case(
+        rng, n, 9, q=q, n_atoms=1, atom_dtype=np.int16, ghost_frac=0.1)
+    ids = rng.integers(0, n_strata, n)
+    ids[:2] = 0, n_strata - 1
+    s = jnp.asarray(ids.astype(np.int16))
+    ftab = jnp.asarray(rng.integers(1, 10 ** 7, n_strata).astype(np.float32))
+    ks = jnp.asarray(rng.uniform(1e3, 1e7, q).astype(np.float32))
+    return v, u, s, ftab, va, atoms, c, ks, consts
+
+
+@pytest.mark.parametrize("n_strata", [4097, 6016, 15000, MAX_FUSED_STRATA])
+def test_fused_kernels_wide_families(n_strata):
+    """Families past 4,096 strata, up to every int16 id: both fused
+    kernels derive freq bit-identically to `freq_table[strat]` (the fused
+    scan equals the pre-fusion kernel fed the gathered freq, bit for bit;
+    the quantile kernel's moments equal the fused scan's) and match the
+    oracle of kernels/ref.py."""
+    v, u, s, ftab, va, atoms, c, ks, consts = args = _wide_case(n_strata, 3)
+    struct = ((CmpOp.LE,),)
+    fused = _assert_fused_parity(args, struct, 9)
+    freq = ftab[s.astype(jnp.int32)]
+    ek = jnp.where(va, u * freq, jnp.inf)
+    old = agg_scan_batched_pallas(v, freq, ek, atoms[0][None].astype(
+        jnp.float32), c.astype(jnp.int32), ks, consts, ops_struct=struct,
+        n_groups=9, interpret=True)
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(old))
+    lo, hi = float(np.asarray(v).min()), float(np.asarray(v).max())
+    mom, hist = quantile_scan_pallas(
+        v, u, s, ftab, va, atoms, c, ks[0], jnp.float32(lo), jnp.float32(hi),
+        consts[0], ops_struct=struct, n_groups=9, interpret=True)
+    np.testing.assert_array_equal(np.asarray(mom), np.asarray(fused[0]))
+    w = ((ek < ks[0]) & (atoms[0] <= consts[0, 0])) / jnp.minimum(
+        1.0, ks[0] / freq)
+    want = ref.quantile_hist_ref(v, w, c.astype(jnp.int32), 9, lo, hi, 256)
+    np.testing.assert_allclose(np.asarray(hist), np.asarray(want).T,
+                               rtol=2e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("builder", ["single", "batched", "quantile"])
+def test_served_builders_scan_wide_families_on_the_kernel(builder):
+    """The executor's programs for a 15,000-strata family run the fused
+    Pallas kernel, never the jnp path."""
+    n, d = 4096, 15000
+    rng = np.random.default_rng(60)
+    cols = {"dt": jnp.asarray(rng.integers(0, 30, (1, n)), jnp.int8),
+            "URL": jnp.asarray(rng.integers(0, 500, (1, n)), jnp.int16),
+            "SessionTime": jnp.asarray(rng.random((1, n)), jnp.float32)}
+    tail = (cols, jnp.asarray(rng.random((1, n)), jnp.float32),
+            jnp.asarray(rng.integers(0, d, (1, n)), jnp.int16),
+            jnp.ones(d + 8, jnp.float32), jnp.ones((1, n), bool))
+    struct = ((("dt", CmpOp.EQ), ("URL", CmpOp.EQ)),)
+    vals = ((3.0, 7.0),)
+    if builder == "single":
+        fn = exec_lib.make_query_fn(struct, "SessionTime", None, 1,
+                                    use_pallas=True)
+        args = (jnp.float32(50.0), vals) + tail
+    elif builder == "batched":
+        fn = exec_lib.make_batched_query_fn(struct, "SessionTime", "dt", 30,
+                                            use_pallas=True)
+        args = (jnp.ones(2, jnp.float32), jnp.ones((2, 2), jnp.float32)) \
+            + tail
+    else:
+        fn = exec_lib.make_quantile_fn(struct, "SessionTime", None, 1,
+                                       use_pallas=True)
+        args = (jnp.float32(50.0), vals, jnp.float32(0.5), jnp.float32(0.0),
+                jnp.float32(1.0)) + tail
+    assert "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def test_fused_kernel_rejects_tables_past_int16_ids():
+    """A table longer than the int16 id space is refused at trace time,
+    not scanned with a VMEM block the chip cannot hold."""
+    v, u, s, _, va, atoms, c, ks, consts = _wide_case(4097, 1)
     big = jnp.ones(MAX_FUSED_STRATA + 1, jnp.float32)
     with pytest.raises(ValueError, match="strata"):
         agg_scan_fused_pallas(v, u, s, big, va, atoms, c, ks, consts,
-                              ops_struct=((CmpOp.EQ,),), n_groups=2,
+                              ops_struct=((CmpOp.EQ,),), n_groups=9,
                               interpret=True)
 
 

@@ -583,6 +583,27 @@ def test_every_scan_path_splits_launch_and_wait(path, db, sub_db):
         assert s.attrs["rows_read"] > 0
 
 
+@pytest.mark.parametrize("path", ["single", "batched", "subsampled",
+                                  "quantile", "exact", "sharded"])
+def test_scan_split_names_the_family_strata(path, db, sub_db):
+    """`scan.launch` and `scan.wait` carry the scanned family's stratum
+    count (0 on the base table), and `engine_scanned_rows_total{strata}`
+    splits every row read into wide and narrow."""
+    engine = sub_db if path == "subsampled" else db
+    tr = _run_path(path, db, sub_db)
+    counts = ({0} if path == "exact" else
+              {f.n_distinct for f in engine.families["sessions"].values()})
+    split = [s for s in tr.spans if s.name in ("scan.launch", "scan.wait")]
+    assert split
+    for s in split:
+        assert s.attrs["strata"] in counts, (s.name, s.attrs)
+    rows = engine.metrics.counter("engine_scanned_rows_total",
+                                  labels=("strata",))
+    assert rows.value("wide") == 0   # (City) has 200 strata
+    assert rows.value("narrow") == \
+        engine.metrics.counter("engine_rows_read_total").value() > 0
+
+
 def test_new_spans_keep_the_stage_readings(db):
     """The rule for new spans: a span of a stage Answer.timings and the
     benchmark's readers bill (plan, scan, estimate) either existed before

@@ -63,7 +63,8 @@ def _shape(one_chip, shape, dtype):
 
 
 @pytest.mark.parametrize("q,n_groups,code_dtype,n_strata", [
-    (1, 2000, jnp.int8, MAX_FUSED_STRATA), (64, 1000, jnp.int16, 384)])
+    (1, 2000, jnp.int8, 4096), (64, 1000, jnp.int16, 384),
+    (64, 200, jnp.int16, 15000), (2, 512, jnp.int16, MAX_FUSED_STRATA)])
 def test_fused_scan_compiles_for_v5e(one_chip, compile_v5e, q, n_groups,
                                      code_dtype, n_strata):
     s = lambda shape, dt: _shape(one_chip, shape, dt)   # noqa: E731
@@ -87,6 +88,22 @@ def test_quantile_scan_compiles_for_v5e(one_chip, compile_v5e):
             s((), jnp.float32), s((1,), jnp.float32))
     text = compile_v5e(lambda *a: quantile_scan_pallas(
         *a, ops_struct=((CmpOp.EQ,),), n_groups=200, n_bins=256), *args)
+    assert "tpu_custom_call" in text
+
+
+def test_quantile_scan_compiles_for_v5e_wide_family(one_chip, compile_v5e):
+    """The one-pass QUANTILE kernel over a (day, URL)-like family: 15,000
+    strata, int16 ids."""
+    s = lambda shape, dt: _shape(one_chip, shape, dt)   # noqa: E731
+    n = N_ROWS
+    args = (s((n,), jnp.float32), s((n,), jnp.float32), s((n,), jnp.int16),
+            s((15000,), jnp.float32), s((n,), jnp.bool_),
+            (s((n,), jnp.int8), s((n,), jnp.int16)), s((n,), jnp.int16),
+            s((), jnp.float32), s((), jnp.float32), s((), jnp.float32),
+            s((2,), jnp.float32))
+    text = compile_v5e(lambda *a: quantile_scan_pallas(
+        *a, ops_struct=((CmpOp.EQ, CmpOp.EQ),), n_groups=200, n_bins=256),
+        *args)
     assert "tpu_custom_call" in text
 
 
