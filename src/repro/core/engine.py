@@ -42,17 +42,6 @@ from repro.sharding import placement as place_lib
 WIDE_STRATA = 4096
 
 
-def _launch_and_wait(fn, *args, strata: int):
-    """Run a compiled scan program inside the caller's scan span: the call
-    (argument handling, allocation, enqueue) as `scan.launch`, the wait
-    for every output as `scan.wait`, each with the scanned family's
-    stratum count as `strata`."""
-    with obs_trace.span("scan.launch", strata=strata):
-        out = fn(*args)
-    with obs_trace.span("scan.wait", strata=strata):
-        return jax.tree.map(lambda x: x.block_until_ready(), out)
-
-
 @dataclasses.dataclass
 class EngineConfig:
     k1: float = 100_000.0        # largest stratification cap (paper §6.1: 1e5)
@@ -192,6 +181,10 @@ class BlinkDB:
             labels=("path",))
         self._m_rows_read = self.metrics.counter(
             "engine_rows_read_total", "Sample/base rows scanned on device")
+        self._m_rows_visited = self.metrics.counter(
+            "engine_rows_visited_total",
+            "Rows the scan programs' grids visited on device (whole kernel "
+            "row blocks; the whole block on the jnp path)")
         self._m_scanned_rows = self.metrics.counter(
             "engine_scanned_rows_total",
             "Rows scanned on device, by the family's stratum count: wide "
@@ -980,6 +973,28 @@ class BlinkDB:
                 "dead_bytes": rb * (tbl.n_rows - tbl.n_live)
                 + sample_rb * ghost_rows}
 
+    def _launch_and_wait(self, fn, *args, strata: int, rows_visited: int):
+        """Run a compiled scan program inside the caller's scan span: the
+        call (argument handling, allocation, enqueue) as `scan.launch`, the
+        wait for every output as `scan.wait`, each with the scanned family's
+        stratum count as `strata`; `scan.launch` also carries the rows the
+        program's grid visits, counted in engine_rows_visited_total."""
+        self._m_rows_visited.inc(rows_visited)
+        with obs_trace.span("scan.launch", strata=strata,
+                            rows_visited=rows_visited):
+            out = fn(*args)
+        with obs_trace.span("scan.wait", strata=strata):
+            return jax.tree.map(lambda x: x.block_until_ready(), out)
+
+    def _row_grid(self, striped: exec_lib.StripedFamily, struct, k: float,
+                  quantile: bool = False) -> tuple[np.ndarray, int]:
+        """The kernel's row spans for a scan at cap `k` (the batch's
+        largest) and the rows its grid then visits."""
+        spans = exec_lib.row_spans(striped, k)
+        kernel = exec_lib.on_kernel(struct, self.config.use_pallas,
+                                    quantile=quantile, mesh=self.mesh)
+        return spans, exec_lib.rows_visited(striped, spans, kernel)
+
     def _compile(self, program: str, jfn, *args):
         """AOT-compile `jfn` for `args` (no execution) in a `compile` span,
         counted in `engine_program_compiles_total{program}`."""
@@ -1010,6 +1025,7 @@ class BlinkDB:
         key = (table_name, phi, struct, q.value_column, group_col, n_groups,
                striped.shape_class)
         args = exec_lib.scan_args(striped)
+        spans, visited = self._row_grid(striped, struct, k)
         fn = self._programs.get(key)
         if fn is None:
             jfn = exec_lib.make_query_fn(
@@ -1018,7 +1034,8 @@ class BlinkDB:
                 use_pallas=self.config.use_pallas)
             # AOT-compile (no execution) so the cold path runs the query
             # exactly once: the timed call below both warms and answers.
-            fn = self._compile("query", jfn, jnp.float32(k), vals, *args)
+            fn = self._compile("query", jfn, jnp.float32(k), vals, *args,
+                               spans)
             self._programs[key] = fn
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, k=float(k)) as sp:
@@ -1026,10 +1043,11 @@ class BlinkDB:
             report = None
             if self._fault_sharding_active():
                 def call(mask):
-                    return _launch_and_wait(
+                    return self._launch_and_wait(
                         fn, jnp.float32(k), vals, striped.columns,
                         striped.unit, striped.strat, striped.freq_table,
-                        mask, strata=fam.n_distinct)
+                        mask, spans, strata=fam.n_distinct,
+                        rows_visited=visited)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1038,8 +1056,9 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = _launch_and_wait(fn, jnp.float32(k), vals, *args,
-                                       strata=fam.n_distinct)
+                mom = self._launch_and_wait(fn, jnp.float32(k), vals, *args,
+                                            spans, strata=fam.n_distinct,
+                                            rows_visited=visited)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
             sp.set(rows_read=rows)
@@ -1143,7 +1162,7 @@ class BlinkDB:
         the predicate constants, the level, the histogram range, AND the
         striped block are traced args, so every re-instantiation (and every
         ELP probe) reuses one compiled program, including across incremental
-        appends."""
+        appends. Also returns the rows the program's grid visits."""
         striped = self._striped_for(table_name, phi)
         bound_pred = exec_lib.bind_predicate(q.predicate, self._encode(table_name))
         struct, vals = exec_lib.pred_structure(bound_pred)
@@ -1152,9 +1171,10 @@ class BlinkDB:
         key = (table_name, phi, struct, q.value_column, group_col, n_groups,
                striped.shape_class)
         lo, hi = self._family_range(table_name, phi, q.value_column)
+        spans, visited = self._row_grid(striped, struct, k, quantile=True)
         args = (jnp.float32(k), vals, jnp.float32(q.quantile),
                 jnp.float32(lo), jnp.float32(hi),
-                *exec_lib.scan_args(striped))
+                *exec_lib.scan_args(striped), spans)
         fn = self._quantile_programs.get(key)
         if fn is None:
             jfn = exec_lib.make_quantile_fn(struct, q.value_column, group_col,
@@ -1163,7 +1183,7 @@ class BlinkDB:
                                             use_pallas=self.config.use_pallas)
             fn = self._compile("quantile", jfn, *args)
             self._quantile_programs[key] = fn
-        return fn, args
+        return fn, args, visited
 
     def _run_quantile_at_k(self, table_name: str, q: Query,
                            phi: tuple[str, ...], k: float):
@@ -1172,13 +1192,14 @@ class BlinkDB:
         this off the fault-sharded path (per-shard moment partials need the
         plain scan program); timed like _run_at_k."""
         fam = self.families[table_name][phi]
-        fn, args = self._quantile_program(q, table_name, phi, k)
+        fn, args, visited = self._quantile_program(q, table_name, phi, k)
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, k=float(k),
                             quantile=True) as sp:
             t0 = time.perf_counter()
-            mom, qv, dens = _launch_and_wait(fn, *args,
-                                             strata=fam.n_distinct)
+            mom, qv, dens = self._launch_and_wait(fn, *args,
+                                                  strata=fam.n_distinct,
+                                                  rows_visited=visited)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
             sp.set(rows_read=rows)
@@ -1208,7 +1229,7 @@ class BlinkDB:
         if q.agg is not AggOp.QUANTILE:
             return est_lib.estimate(q.agg, mom)
         if qpair is None:
-            fn, args = self._quantile_program(q, table_name, phi, k)
+            fn, args, _ = self._quantile_program(q, table_name, phi, k)
             _, qv, dens = fn(*args)
             qpair = (qv, dens)
         return est_lib.estimate(AggOp.QUANTILE, mom, quantile_value=qpair[0],
@@ -1269,9 +1290,10 @@ class BlinkDB:
                         struct, q.value_column, group_col, n_groups, b,
                         mesh=self.mesh, data_axes=self.data_axes)
                     self._subsampled_quantile_programs[key] = fn
-                mom_sub, qv, dens, qsub = _launch_and_wait(
+                mom_sub, qv, dens, qsub = self._launch_and_wait(
                     fn, jnp.float32(k), vals, jnp.float32(q.quantile), sub,
-                    *args, strata=fam.n_distinct)
+                    *args, strata=fam.n_distinct,
+                    rows_visited=striped.capacity)
                 est = est_lib.subsampling_estimate(
                     AggOp.QUANTILE, mom_sub, n_groups, b, quantile_value=qv,
                     quantile_density=dens, quantile_values_sub=qsub,
@@ -1283,8 +1305,9 @@ class BlinkDB:
                         struct, q.value_column, group_col, n_groups, b,
                         mesh=self.mesh, data_axes=self.data_axes)
                     self._subsampled_programs[key] = fn
-                mom_sub = _launch_and_wait(fn, jnp.float32(k), vals, sub,
-                                           *args, strata=fam.n_distinct)
+                mom_sub = self._launch_and_wait(
+                    fn, jnp.float32(k), vals, sub, *args,
+                    strata=fam.n_distinct, rows_visited=striped.capacity)
                 est = est_lib.subsampling_estimate(q.agg, mom_sub, n_groups, b)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(k)
@@ -1688,6 +1711,7 @@ class BlinkDB:
             np.float32).reshape(q_pad, n_atoms)
         ks_dev, consts_dev = jnp.asarray(ks_arr), jnp.asarray(consts)
         args = exec_lib.scan_args(striped)
+        spans, visited = self._row_grid(striped, struct, max(ks))
         pkey = scan_key + (striped.shape_class, q_pad)
         fn = self._batched_programs.get(pkey)
         if fn is None:
@@ -1695,7 +1719,8 @@ class BlinkDB:
                 struct, value_col, group_col, n_groups,
                 mesh=self.mesh, data_axes=self.data_axes,
                 use_pallas=self.config.use_pallas)
-            fn = self._compile("batched", jfn, ks_dev, consts_dev, *args)
+            fn = self._compile("batched", jfn, ks_dev, consts_dev, *args,
+                               spans)
             self._batched_programs[pkey] = fn
         inject.site("engine.scan", table=table_name)
         with obs_trace.span("scan", table=table_name, batch=n_q,
@@ -1704,10 +1729,11 @@ class BlinkDB:
             report = None
             if self._fault_sharding_active():
                 def call(mask):
-                    return _launch_and_wait(
+                    return self._launch_and_wait(
                         fn, ks_dev, consts_dev, striped.columns,
                         striped.unit, striped.strat, striped.freq_table,
-                        mask, strata=fam.n_distinct)
+                        mask, spans, strata=fam.n_distinct,
+                        rows_visited=visited)
                 mom, report = exec_lib.run_sharded_scan(
                     call, striped,
                     n_logical=self.config.n_logical_shards,
@@ -1716,8 +1742,9 @@ class BlinkDB:
                     deadline_s=self.config.straggler_deadline_s,
                     placement=self._placement_for(table_name, phi))
             else:
-                mom = _launch_and_wait(fn, ks_dev, consts_dev, *args,
-                                       strata=fam.n_distinct)
+                mom = self._launch_and_wait(fn, ks_dev, consts_dev, *args,
+                                            spans, strata=fam.n_distinct,
+                                            rows_visited=visited)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(max(ks))
             sp.set(rows_read=rows)
@@ -1780,8 +1807,9 @@ class BlinkDB:
                             k=float(max(ks)), subsampled=True) as sp:
             t0 = time.perf_counter()
             fam = self.families[table_name][phi]
-            mom = _launch_and_wait(fn, ks_dev, consts_dev, sub, *args,
-                                   strata=fam.n_distinct)
+            mom = self._launch_and_wait(fn, ks_dev, consts_dev, sub, *args,
+                                        strata=fam.n_distinct,
+                                        rows_visited=striped.capacity)
             dt = time.perf_counter() - t0
             rows = fam.prefix_for_k(max(ks))
             sp.set(rows_read=rows)
@@ -2004,8 +2032,11 @@ class BlinkDB:
 
         with obs_trace.span("scan.exact", table=q.table) as sp:
             t0 = time.perf_counter()
-            # The base table is no family: strata 0.
-            with obs_trace.span("scan.launch", strata=0):
+            # The base table is no family: strata 0; the program visits
+            # every physical row.
+            self._m_rows_visited.inc(tbl.n_rows)
+            with obs_trace.span("scan.launch", strata=0,
+                                rows_visited=tbl.n_rows):
                 mom = fn(vals, tcols, live)
             # The eager QUANTILE pass and the estimate are host-dispatched
             # device work that waits on the program's moments: scan.wait.

@@ -55,7 +55,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import estimators as est_lib
-from repro.core.sampling import SampleFamily
+from repro.core.sampling import SampleFamily, count_below
 from repro.core.types import (AggOp, Atom, CmpOp, Conjunction, Predicate,
                               cmp_fns)
 from repro.fault import inject
@@ -166,6 +166,16 @@ class StripedFamily:
     # Self-excluded slots: rescale ghosts (rows pushed past K₁ by a merge)
     # plus tombstoned rows. Drives the compaction trigger.
     n_ghosts: int = 0
+    # Row-grid bound (row_spans): the first `n_sorted` slots are in entry-key
+    # order, and key_floor[j] is a non-decreasing lower bound of slot j's
+    # key unit·freq_table[strat] as the kernels compute it, taken when the
+    # block was striped. A tombstone (unit := +inf) and an append (F only
+    # grows, sampling.merge_family) only raise a slot's key, so the floor
+    # holds until the next restripe; appended slots lie past n_sorted, out
+    # of key order, and every scan visits them.
+    key_floor: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0, np.float32))
+    n_sorted: int = 0
 
     @property
     def capacity(self) -> int:
@@ -328,10 +338,16 @@ def stripe_family(fam: SampleFamily, n_shards: int,
     slot_row_ids = (fam.row_ids.astype(np.int64).copy()
                     if fam.row_ids is not None
                     else np.full(n, -1, dtype=np.int64))
+    # The kernels' f32 key of every slot; a suffix minimum makes a floor
+    # that is non-decreasing even where the keys are not.
+    keys = (unit.astype(np.float32)
+            * host_block["freq_table"][np.asarray(strat, dtype=np.int64)])
+    key_floor = np.minimum.accumulate(keys[::-1])[::-1]
     return StripedFamily(fam.phi, fam.ks, dev["cols"], dev["valid"],
                          dev["unit"], dev["strat"], dev["freq_table"],
                          n, fam.table_rows, n_shards,
-                         slot_row_ids=slot_row_ids, n_ghosts=0)
+                         slot_row_ids=slot_row_ids, n_ghosts=0,
+                         key_floor=key_floor, n_sorted=n)
 
 
 def _pad_pow2(a: np.ndarray, d: int) -> np.ndarray:
@@ -427,7 +443,9 @@ def stripe_append(striped: StripedFamily, fam: SampleFamily,
                          slot_row_ids=np.concatenate([old_ids, new_ids]),
                          # rows the rescale pushed past K₁ stay in the block
                          # as self-excluded ghosts until compaction
-                         n_ghosts=striped.n_ghosts + block.n_dropped_old)
+                         n_ghosts=striped.n_ghosts + block.n_dropped_old,
+                         key_floor=striped.key_floor,
+                         n_sorted=striped.n_sorted)
 
 
 @jax.jit
@@ -502,6 +520,36 @@ def scan_args(striped: StripedFamily) -> tuple:
     definition so engine call sites and tests cannot drift."""
     return (striped.columns, striped.unit, striped.strat,
             striped.freq_table, striped.valid)
+
+
+def row_spans(striped: StripedFamily, k: float) -> np.ndarray:
+    """int32[3] (head_end, tail_start, tail_end): the local rows of each
+    shard that a scan at resolution cap `k` has to visit, [0, head_end) ∪
+    [tail_start, tail_end); the kernels' `row_spans`. The head covers every
+    key-ordered slot whose key floor is below k rounded up to f32
+    (sampling.count_below, the same rule as the rows counted read), which
+    contains every slot the kernel's `unit·freq < float32(k)` can pass; the
+    tail is the appended slots [n_sorted, n_rows). Slot j lives at local
+    row j // S, so with S shards the head is ⌈slots / S⌉ rows."""
+    s = striped.n_shards
+    head_end = -(-count_below(striped.key_floor, k) // s)
+    if striped.n_sorted >= striped.n_rows:
+        return np.array([head_end, head_end, head_end], np.int32)
+    return np.array([head_end, striped.n_sorted // s,
+                     -(-striped.n_rows // s)], np.int32)
+
+
+def rows_visited(striped: StripedFamily, spans: np.ndarray,
+                 kernel: bool) -> int:
+    """Rows a scan program's grid visits over all shards: the kernel row
+    blocks that meet the spans, or the whole block on the jnp path (which
+    masks rows rather than skipping them)."""
+    if not kernel:
+        return striped.capacity
+    from repro.kernels.agg_scan import (DEFAULT_BLOCK_ROWS_BATCHED as b,
+                                        visit_plan)
+    steps = visit_plan(spans, -(-striped.n_local // b), b, xp=np)[2]
+    return int(steps) * b * striped.n_shards
 
 
 def derive_ht(unit: jax.Array, strat: jax.Array, freq_table: jax.Array
@@ -604,15 +652,29 @@ def dedup_atom_slots(atoms) -> tuple[tuple[str, ...], tuple[int, ...]]:
     return tuple(names), tuple(slots)
 
 
+def on_kernel(struct, use_pallas: bool, *, quantile: bool = False,
+              mesh: Mesh | None = None) -> bool:
+    """Whether a scan program built for `struct` runs the fused kernel.
+    Its qconst block holds k (and, for QUANTILE, lo and hi) beside the
+    predicate's constants, in CONST_LANES lanes; wider templates, and
+    QUANTILE on a mesh (which psums jnp partials), run the jnp path."""
+    from repro.kernels.agg_scan import CONST_LANES
+    lanes = len(flat_atoms(struct)) + (3 if quantile else 1)
+    return (use_pallas and lanes <= CONST_LANES
+            and not (quantile and mesh is not None))
+
+
 def make_query_fn(struct, value_col: str | None,
                   group_col: str | None, n_groups: int,
                   mesh: Mesh | None = None,
                   data_axes: tuple[str, ...] = ("data",),
                   use_pallas: bool = False):
     """Compile the fused query program once per (family × template).
-    Returns jitted fn(k, pred_vals, cols, unit, strat, freq_table, valid) ->
-    GroupedMoments over the primitive memory-lean layout (scan_args order;
-    freq/entry_key are derived in-scan). k and the predicate constants are
+    Returns jitted fn(k, pred_vals, cols, unit, strat, freq_table, valid,
+    spans=None) -> GroupedMoments over the primitive memory-lean layout
+    (scan_args order; freq/entry_key are derived in-scan). `spans`
+    (row_spans) bounds the kernel's row grid; the jnp path scans every
+    row whatever it says. k, the spans and the predicate constants are
     traced, so re-instantiations don't retrace — and the striped block
     itself is a TRACED ARGUMENT rather than a captured constant, so an
     incremental append that keeps the padded shape class
@@ -622,13 +684,10 @@ def make_query_fn(struct, value_col: str | None,
     VMEM)."""
     atoms = flat_atoms(struct)
     ops_struct = tuple(tuple(op for _, op in conj) for conj in struct)
-    if use_pallas:
-        from repro.kernels.agg_scan import CONST_LANES
-        if len(atoms) + 1 > CONST_LANES:
-            use_pallas = False
+    use_pallas = on_kernel(struct, use_pallas)
     acol_names, atom_slots = dedup_atom_slots(atoms)
 
-    def shard_fn(k, pred_vals, cols, unit, strat, ftab, valid):
+    def shard_fn(k, pred_vals, spans, cols, unit, strat, ftab, valid):
         values = (cols[value_col].astype(jnp.float32)
                   if value_col is not None else jnp.ones_like(unit))
         gcodes = (cols[group_col].astype(jnp.int32)
@@ -641,7 +700,7 @@ def make_query_fn(struct, value_col: str | None,
             mom = kops.agg_scan_fused(
                 values, unit, strat, ftab, valid, acols, gcodes,
                 jnp.asarray(k, jnp.float32)[None], consts[None, :],
-                ops_struct, atom_slots, n_groups)
+                ops_struct, atom_slots, n_groups, spans)
             return jax.tree.map(lambda x: x[0], mom)
         freq, ek = derive_ht(unit, strat, ftab)
         mask = eval_pred(struct, cols, pred_vals) & valid & (ek < k)
@@ -649,20 +708,23 @@ def make_query_fn(struct, value_col: str | None,
         return est_lib.grouped_moments(values, rates, mask, gcodes, n_groups)
 
     if mesh is None:
-        def fn(k, pred_vals, cols, unit, strat, freq_table, valid):
+        def fn(k, pred_vals, cols, unit, strat, freq_table, valid,
+               spans=None):
             mom = jax.vmap(lambda c, u, s, v: shard_fn(
-                k, pred_vals, c, u, s, freq_table, v))(cols, unit, strat, valid)
+                k, pred_vals, spans, c, u, s, freq_table, v)
+            )(cols, unit, strat, valid)
             return jax.tree.map(lambda x: x.sum(axis=0), mom)
         return jax.jit(fn)
 
     pspec = P(data_axes)
 
-    def fn(k, pred_vals, cols, unit, strat, freq_table, valid):
+    def fn(k, pred_vals, cols, unit, strat, freq_table, valid, spans=None):
         inner = jax.shard_map(
             lambda c, u, s, ft, v: _merge_psum(
                 jax.tree.map(lambda x: x[0],
                              jax.vmap(lambda cc, uu, ss, vv: shard_fn(
-                                 k, pred_vals, cc, uu, ss, ft, vv))(c, u, s, v)),
+                                 k, pred_vals, spans, cc, uu, ss, ft, vv)
+                             )(c, u, s, v)),
                 data_axes),
             mesh=mesh,
             in_specs=(pspec, pspec, pspec, P(), pspec),
@@ -686,8 +748,9 @@ def make_batched_query_fn(struct,
                           use_pallas: bool = False):
     """Compile ONE fused multi-query program per (family × template).
 
-    Returns jitted fn(ks, pred_consts, cols, unit, strat, freq_table, valid)
-    -> GroupedMoments with leading batch axis: ks is f32[Q] (per-query
+    Returns jitted fn(ks, pred_consts, cols, unit, strat, freq_table, valid,
+    spans=None) -> GroupedMoments with leading batch axis: ks is f32[Q]
+    (per-query
     resolution caps), pred_consts is f32[Q, A] (per-query predicate
     constants in flat_atoms order). Every leaf of the result is
     [Q, n_groups]. The family prefix streams from HBM once for the whole
@@ -696,20 +759,15 @@ def make_batched_query_fn(struct,
     striped block is a traced argument so appends that preserve the padded
     shape class keep compiled programs valid. The pallas path is the fused
     memory-lean kernel: narrow columns stream as stored, the freq table is
-    VMEM-resident, HT state is derived per block.
+    VMEM-resident, HT state is derived per block, and `spans` (row_spans
+    at the batch's largest k) bounds its row grid.
     """
     atoms = flat_atoms(struct)
     ops_struct = tuple(tuple(op for _, op in conj) for conj in struct)
-    if use_pallas:
-        from repro.kernels.agg_scan import CONST_LANES
-        if len(atoms) + 1 > CONST_LANES:
-            # The Q-query kernel packs k + atom constants into one
-            # CONST_LANES-wide qconst block; wider templates fall back to
-            # the jnp path rather than failing at trace time.
-            use_pallas = False
+    use_pallas = on_kernel(struct, use_pallas)
     acol_names, atom_slots = dedup_atom_slots(atoms)
 
-    def shard_fn(ks, pred_consts, cols, unit, strat, ftab, valid):
+    def shard_fn(ks, pred_consts, spans, cols, unit, strat, ftab, valid):
         values = (cols[value_col].astype(jnp.float32)
                   if value_col is not None else jnp.ones_like(unit))
         gcodes = (cols[group_col].astype(jnp.int32)
@@ -719,7 +777,8 @@ def make_batched_query_fn(struct,
             acols = tuple(cols[c] for c in acol_names)
             return kops.agg_scan_fused(values, unit, strat, ftab, valid,
                                        acols, gcodes, ks, pred_consts,
-                                       ops_struct, atom_slots, n_groups)
+                                       ops_struct, atom_slots, n_groups,
+                                       spans)
         freq, ek = derive_ht(unit, strat, ftab)
 
         def one(k, consts):
@@ -730,21 +789,22 @@ def make_batched_query_fn(struct,
         return jax.vmap(one)(ks, pred_consts)
 
     if mesh is None:
-        def fn(ks, pred_consts, cols, unit, strat, freq_table, valid):
+        def fn(ks, pred_consts, cols, unit, strat, freq_table, valid,
+               spans=None):
             mom = jax.vmap(lambda c, u, s, v: shard_fn(
-                ks, pred_consts, c, u, s, freq_table, v)
+                ks, pred_consts, spans, c, u, s, freq_table, v)
             )(cols, unit, strat, valid)
             return jax.tree.map(lambda x: x.sum(axis=0), mom)
         return jax.jit(fn)
 
     pspec = P(data_axes)
 
-    def fn(ks, pred_consts, cols, unit, strat, freq_table, valid):
+    def fn(ks, pred_consts, cols, unit, strat, freq_table, valid, spans=None):
         def per_shard(c, u, s, ft, v):
             mom = jax.tree.map(
                 lambda x: x[0],
                 jax.vmap(lambda cc, uu, ss, vv: shard_fn(
-                    ks, pred_consts, cc, uu, ss, ft, vv))(c, u, s, v))
+                    ks, pred_consts, spans, cc, uu, ss, ft, vv))(c, u, s, v))
             leaves, treedef = jax.tree.flatten(mom)
             # ONE collective for the whole batch: psum the stacked [7, Q, G]
             # statistics tensor instead of seven per-leaf reductions.
@@ -1143,14 +1203,16 @@ def make_quantile_fn(struct, value_col: str, group_col: str | None,
     """ONE-PASS quantile program over a STRIPED block.
 
     Returns jitted fn(k, pred_vals, level, lo, hi, cols, unit, strat,
-    freq_table, valid) -> (GroupedMoments, quantile_value[G], density[G]):
+    freq_table, valid, spans=None) -> (GroupedMoments, quantile_value[G],
+    density[G]):
     the grouped sufficient statistics AND the histogram quantile come out of
     a single streaming pass, so a QUANTILE answer no longer pays a second
     full-column read after the moments scan.
 
     The pallas path runs the fused quantile kernel (moments + bins×groups
     histogram in one VMEM-resident pass) over the family-global [lo, hi]
-    range the engine caches per (family, value column). The jnp path keeps
+    range the engine caches per (family, value column), its row grid
+    bounded by `spans` (row_spans). The jnp path keeps
     the original data-dependent range (lo/hi args unused) so its histogram
     numerics are unchanged from the pre-fusion pass; histogram results are
     order-invariant over the padded striped layout (padding/ghosts carry
@@ -1158,17 +1220,12 @@ def make_quantile_fn(struct, value_col: str, group_col: str | None,
     existing padding reuse the compiled program."""
     atoms = flat_atoms(struct)
     ops_struct = tuple(tuple(op for _, op in conj) for conj in struct)
-    if use_pallas:
-        from repro.kernels.agg_scan import CONST_LANES
-        if len(atoms) + 3 > CONST_LANES or mesh is not None:
-            # qconst lanes 0..2 hold (k, lo, hi); wider templates — and the
-            # mesh path, which psums jnp partials — fall back to jnp.
-            use_pallas = False
+    use_pallas = on_kernel(struct, use_pallas, quantile=True, mesh=mesh)
     acol_names, atom_slots = dedup_atom_slots(atoms)
 
     if use_pallas:
         def fn(k, pred_vals, level, lo, hi, cols, unit, strat, freq_table,
-               valid):
+               valid, spans=None):
             from repro.kernels import ops as kops
             consts = (jnp.stack(list(flatten_pred_vals(pred_vals)))
                       if atoms else jnp.zeros((0,), jnp.float32))
@@ -1181,14 +1238,15 @@ def make_quantile_fn(struct, value_col: str, group_col: str | None,
                 return kops.quantile_scan(values, u, s, freq_table, v,
                                           acols, gcodes, k, lo, hi, consts,
                                           ops_struct, atom_slots, n_groups,
-                                          n_bins)
+                                          n_bins, spans)
             mom, hist = jax.vmap(shard)(cols, unit, strat, valid)
             mom = jax.tree.map(lambda x: x.sum(axis=0), mom)
             qval, dens = hist_to_quantile(hist.sum(axis=0).T, lo, hi, level)
             return mom, qval, dens
         return jax.jit(fn)
 
-    def fn(k, pred_vals, level, lo, hi, cols, unit, strat, freq_table, valid):
+    def fn(k, pred_vals, level, lo, hi, cols, unit, strat, freq_table, valid,
+           spans=None):
         flat = {c: v.reshape(-1) for c, v in cols.items()}
         fqf, ekf = derive_ht(unit.reshape(-1), strat.reshape(-1), freq_table)
         mask = eval_pred(struct, flat, pred_vals) & valid.reshape(-1) \

@@ -67,6 +67,12 @@ kernel streams the minimum bytes per row instead:
   deduplicated tuple of column arrays, so a template touching the same
   column twice streams it once.
 
+Both fused kernels take a traced `row_spans` bound (scalar-prefetched):
+the grid keeps its static shape, but only the row blocks that meet the
+batch's key prefix (and an appended tail) are fetched and reduced; later
+steps repeat the last block index, so no DMA starts, and skip their
+compute (docs/BATCHING.md, "The row grid").
+
 `quantile_scan_pallas` extends the fused kernel with a bins×groups
 histogram output block (same one-hot MXU trick, `wbin[NB, B] @ onehot[B,
 GB]`) so a QUANTILE answer — grouped moments AND the weighted value
@@ -79,6 +85,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.types import cmp_fns
 
@@ -347,9 +354,9 @@ def _eval_dnf(qconst_ref, atom_refs, prefix, *, ops_struct, atom_slots,
     return (prefix & disj).astype(jnp.float32)
 
 
-def _fused_scan_kernel(qconst_ref, ftab_ref, values_ref, unit_ref, strat_ref,
-                       valid_ref, codes_ref, *rest, block_groups: int,
-                       ops_struct, atom_slots):
+def _fused_scan_kernel(plan_ref, qconst_ref, ftab_ref, values_ref,
+                       unit_ref, strat_ref, valid_ref, codes_ref, *rest,
+                       block_groups: int, ops_struct, atom_slots):
     atom_refs, out_ref = rest[:-1], rest[-1]
     gi = pl.program_id(0)   # group-block index (outer)
     ri = pl.program_id(1)   # row-block index (inner; accumulates into out)
@@ -358,35 +365,38 @@ def _fused_scan_kernel(qconst_ref, ftab_ref, values_ref, unit_ref, strat_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    v = values_ref[...].astype(jnp.float32)[None, :]          # [1, B]
-    f = _derive_freq(ftab_ref, strat_ref)                     # [1, B]
-    ek = unit_ref[...].astype(jnp.float32)[None, :] * f       # [1, B]
-    va = valid_ref[...][None, :]                              # [1, B] bool
-    codes = codes_ref[...].astype(jnp.int32)
-    ks = qconst_ref[:, 0:1]                                   # [Qp, 1]
+    @pl.when(ri < plan_ref[2])   # steps past the visited blocks do nothing
+    def _accumulate():
+        v = values_ref[...].astype(jnp.float32)[None, :]      # [1, B]
+        f = _derive_freq(ftab_ref, strat_ref)                 # [1, B]
+        ek = unit_ref[...].astype(jnp.float32)[None, :] * f   # [1, B]
+        va = valid_ref[...][None, :]                          # [1, B] bool
+        codes = codes_ref[...].astype(jnp.int32)
+        ks = qconst_ref[:, 0:1]                               # [Qp, 1]
 
-    prefix = (ek < ks) & va                                   # [Qp, B]
-    mf = _eval_dnf(qconst_ref, atom_refs, prefix,
-                   ops_struct=ops_struct, atom_slots=atom_slots, lane_base=1)
+        prefix = (ek < ks) & va                               # [Qp, B]
+        mf = _eval_dnf(qconst_ref, atom_refs, prefix, ops_struct=ops_struct,
+                       atom_slots=atom_slots, lane_base=1)
 
-    r = jnp.minimum(1.0, ks / f)                              # [Qp, B]
-    w = mf / r
-    wx = w * v
-    vfac = mf * (1.0 - r) / (r * r)
-    vx = vfac * v
-    # Stat-major stacking: row s*Qp + q holds statistic s of query q.
-    stats = jnp.concatenate([
-        mf, w, wx, wx * v, vfac, vx, vx * v,
-        jnp.zeros_like(mf),                   # pad to N_STATS sublane groups
-    ], axis=0)                                                # [8·Qp, B]
+        r = jnp.minimum(1.0, ks / f)                          # [Qp, B]
+        w = mf / r
+        wx = w * v
+        vfac = mf * (1.0 - r) / (r * r)
+        vx = vfac * v
+        # Stat-major stacking: row s*Qp + q holds statistic s of query q.
+        stats = jnp.concatenate([
+            mf, w, wx, wx * v, vfac, vx, vx * v,
+            jnp.zeros_like(mf),               # pad to N_STATS sublane groups
+        ], axis=0)                                            # [8·Qp, B]
 
-    group_base = gi * block_groups
-    gids = group_base + jax.lax.broadcasted_iota(jnp.int32, (1, block_groups), 1)
-    onehot = (codes[:, None] == gids).astype(jnp.float32)     # [B, GB]
+        group_base = gi * block_groups
+        gids = group_base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_groups), 1)
+        onehot = (codes[:, None] == gids).astype(jnp.float32)  # [B, GB]
 
-    out_ref[...] += jax.lax.dot_general(                      # [8·Qp, GB]
-        stats, onehot, (((1,), (0,)), ((), ())),
-        precision=_EXACT, preferred_element_type=jnp.float32)
+        out_ref[...] += jax.lax.dot_general(                  # [8·Qp, GB]
+            stats, onehot, (((1,), (0,)), ((), ())),
+            precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 def _pad_ftab(freq_table: jax.Array) -> jax.Array:
@@ -419,6 +429,50 @@ def _normalize_atoms(atom_cols, ops_struct, atom_slots, n_rows):
     return tuple(atom_cols), atom_slots
 
 
+def visit_plan(row_spans, n_row_blocks: int, block_rows: int, xp=jnp):
+    """int32[3] (head blocks, first tail block, grid steps that visit a
+    block) for `row_spans` = (head_end, tail_start, tail_end) in rows: the
+    row blocks that meet [0, head_end) ∪ [tail_start, tail_end). Traced
+    (`xp=jnp`) inside the kernels' wrappers; `xp=np` gives the same plan on
+    the host, for counting the rows a grid visits."""
+    sp = xp.asarray(row_spans, xp.int32)
+    head = xp.minimum(-(-sp[0] // block_rows), n_row_blocks)
+    t0 = xp.clip(sp[1] // block_rows, head, n_row_blocks)
+    t1 = xp.clip(-(-sp[2] // block_rows), t0, n_row_blocks)
+    return xp.stack([head, t0, head + t1 - t0])
+
+
+def _bounded_grid(n_group_blocks: int, n_row_blocks: int, block_rows: int,
+                  const_shapes, n_streamed: int, out_blocks):
+    """Grid (group blocks, row blocks) whose row index maps follow the
+    scalar-prefetched `visit_plan`: VMEM-resident constant blocks first,
+    then `n_streamed` row-blocked columns; one [rows, group block] output
+    block per entry of `out_blocks`. Row blocks are 1-D over the flat padded
+    columns: Mosaic requires a block's last two dims to tile (8, 128) or
+    span the array, which a (1, B) block over a [n_blocks, B] reshape does
+    not."""
+    row_spec = pl.BlockSpec(
+        (block_rows,),
+        lambda gi, ri, plan: (_row_block(ri, plan, n_row_blocks),))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_group_blocks, n_row_blocks),
+        in_specs=[pl.BlockSpec(sh, lambda gi, ri, plan: (0, 0))
+                  for sh in const_shapes] + [row_spec] * n_streamed,
+        out_specs=[pl.BlockSpec(sh, lambda gi, ri, plan: (0, gi))
+                   for sh in out_blocks])
+
+
+def _row_block(ri, plan_ref, n_row_blocks: int):
+    """Row block of grid step `ri` under a `visit_plan`: the head blocks,
+    then the tail's; a step past the last visited block repeats it, so its
+    block index does not change and no DMA starts for it."""
+    head, t0, steps = plan_ref[0], plan_ref[1], plan_ref[2]
+    r = jnp.minimum(ri, jnp.maximum(steps - 1, 0))
+    return jnp.clip(jnp.where(r < head, r, r - head + t0), 0,
+                    n_row_blocks - 1)
+
+
 @functools.partial(jax.jit, static_argnames=("ops_struct", "atom_slots",
                                              "n_groups", "block_rows",
                                              "block_groups", "interpret"))
@@ -429,7 +483,8 @@ def agg_scan_fused_pallas(values: jax.Array, unit: jax.Array,
                           ops_struct, atom_slots=None, n_groups: int,
                           block_rows: int = DEFAULT_BLOCK_ROWS_BATCHED,
                           block_groups: int = DEFAULT_BLOCK_GROUPS,
-                          interpret: bool = False) -> jax.Array:
+                          interpret: bool = False,
+                          row_spans: jax.Array | None = None) -> jax.Array:
     """Memory-lean Q-query shared scan: returns f32[Q, 7, n_groups].
 
     Streams only the primitive layout — values (f32), unit (f32), strat
@@ -444,6 +499,13 @@ def agg_scan_fused_pallas(values: jax.Array, unit: jax.Array,
     i (default: identity). Padding rows are masked by unit=+inf ⇒
     entry_key=+inf failing every prefix test, so narrow-dtype pad fills
     never contribute.
+
+    `row_spans` (traced int32[3], scalar-prefetched) bounds the row grid:
+    only the row blocks that meet [0, head_end) ∪ [tail_start, tail_end)
+    are fetched and reduced. The caller guarantees that no row outside
+    those spans passes the prefix test of any query, so the result is
+    bit-identical to the full grid (None), which a skipped block would
+    only have added exact zeros to.
     """
     n = values.shape[0]
     q = ks.shape[0]
@@ -480,32 +542,25 @@ def agg_scan_fused_pallas(values: jax.Array, unit: jax.Array,
             pred_consts.astype(jnp.float32))
 
     n_row_blocks = n_pad // block_rows
-    n_group_blocks = g_pad // bg
-    # 1-D row blocks over the flat padded columns: Mosaic requires a
-    # block's last two dims to tile (8, 128) or span the array, which a
-    # (1, B) block over a [n_blocks, B] reshape does not.
-    row_spec = pl.BlockSpec((block_rows,), lambda gi, ri: (ri,))
-
-    out = pl.pallas_call(
+    plan = (jnp.full((3,), n_row_blocks, jnp.int32) if row_spans is None
+            else visit_plan(row_spans, n_row_blocks, block_rows))
+    (out,) = pl.pallas_call(
         functools.partial(_fused_scan_kernel, block_groups=bg,
                           ops_struct=ops_struct, atom_slots=atom_slots),
-        grid=(n_group_blocks, n_row_blocks),
-        in_specs=[
-            pl.BlockSpec((q_pad, CONST_LANES), lambda gi, ri: (0, 0)),
-            pl.BlockSpec(ftab.shape, lambda gi, ri: (0, 0)),
-            row_spec, row_spec, row_spec, row_spec, row_spec,
-        ] + [row_spec] * len(acols),
-        out_specs=pl.BlockSpec((N_STATS * q_pad, bg), lambda gi, ri: (0, gi)),
-        out_shape=jax.ShapeDtypeStruct((N_STATS * q_pad, g_pad), jnp.float32),
+        grid_spec=_bounded_grid(g_pad // bg, n_row_blocks, block_rows,
+                                [qconst.shape, ftab.shape], 5 + len(acols),
+                                [(N_STATS * q_pad, bg)]),
+        out_shape=[jax.ShapeDtypeStruct((N_STATS * q_pad, g_pad),
+                                        jnp.float32)],
         interpret=interpret,
-    )(qconst, ftab, v, u, s, va, c, *acols)
+    )(plan, qconst, ftab, v, u, s, va, c, *acols)
     # stat-major rows → [Q, 7, n_groups]
     out = out.reshape(N_STATS, q_pad, g_pad)
     return out[:7, :q, :n_groups].transpose(1, 0, 2)
 
 
-def _fused_quantile_kernel(qconst_ref, ftab_ref, values_ref, unit_ref,
-                           strat_ref, valid_ref, codes_ref, *rest,
+def _fused_quantile_kernel(plan_ref, qconst_ref, ftab_ref, values_ref,
+                           unit_ref, strat_ref, valid_ref, codes_ref, *rest,
                            block_groups: int, ops_struct, atom_slots,
                            n_bins: int):
     atom_refs, mom_ref, hist_ref = rest[:-2], rest[-2], rest[-1]
@@ -517,50 +572,54 @@ def _fused_quantile_kernel(qconst_ref, ftab_ref, values_ref, unit_ref,
         mom_ref[...] = jnp.zeros_like(mom_ref)
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    k = qconst_ref[0, 0]
-    lo = qconst_ref[0, 1]
-    hi = qconst_ref[0, 2]
+    @pl.when(ri < plan_ref[2])   # steps past the visited blocks do nothing
+    def _accumulate():
+        k = qconst_ref[0, 0]
+        lo = qconst_ref[0, 1]
+        hi = qconst_ref[0, 2]
 
-    v = values_ref[...].astype(jnp.float32)[None, :]          # [1, B]
-    f = _derive_freq(ftab_ref, strat_ref)                     # [1, B]
-    ek = unit_ref[...].astype(jnp.float32)[None, :] * f
-    va = valid_ref[...][None, :]
-    codes = codes_ref[...].astype(jnp.int32)
+        v = values_ref[...].astype(jnp.float32)[None, :]      # [1, B]
+        f = _derive_freq(ftab_ref, strat_ref)                 # [1, B]
+        ek = unit_ref[...].astype(jnp.float32)[None, :] * f
+        va = valid_ref[...][None, :]
+        codes = codes_ref[...].astype(jnp.int32)
 
-    prefix = (ek < k) & va                                    # [1, B]
-    mf = _eval_dnf(qconst_ref[0:1], atom_refs, prefix,
-                   ops_struct=ops_struct, atom_slots=atom_slots, lane_base=3)
+        prefix = (ek < k) & va                                # [1, B]
+        mf = _eval_dnf(qconst_ref[0:1], atom_refs, prefix,
+                       ops_struct=ops_struct, atom_slots=atom_slots,
+                       lane_base=3)
 
-    r = jnp.minimum(1.0, k / f)
-    w = mf / r
-    wx = w * v
-    vfac = mf * (1.0 - r) / (r * r)
-    vx = vfac * v
-    stats = jnp.concatenate([
-        mf, w, wx, wx * v, vfac, vx, vx * v,
-        jnp.zeros_like(mf),
-    ], axis=0)                                                # [8, B]
+        r = jnp.minimum(1.0, k / f)
+        w = mf / r
+        wx = w * v
+        vfac = mf * (1.0 - r) / (r * r)
+        vx = vfac * v
+        stats = jnp.concatenate([
+            mf, w, wx, wx * v, vfac, vx, vx * v,
+            jnp.zeros_like(mf),
+        ], axis=0)                                            # [8, B]
 
-    group_base = gi * block_groups
-    gids = group_base + jax.lax.broadcasted_iota(jnp.int32, (1, block_groups), 1)
-    onehot = (codes[:, None] == gids).astype(jnp.float32)     # [B, GB]
+        group_base = gi * block_groups
+        gids = group_base + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_groups), 1)
+        onehot = (codes[:, None] == gids).astype(jnp.float32)  # [B, GB]
 
-    mom_ref[...] += jax.lax.dot_general(                      # [8, GB]
-        stats, onehot, (((1,), (0,)), ((), ())),
-        precision=_EXACT, preferred_element_type=jnp.float32)
+        mom_ref[...] += jax.lax.dot_general(                  # [8, GB]
+            stats, onehot, (((1,), (0,)), ((), ())),
+            precision=_EXACT, preferred_element_type=jnp.float32)
 
-    # Weighted value histogram over the family-global [lo, hi] range,
-    # reduced by the SAME resident onehot: wbin[NB, B] @ onehot[B, GB].
-    span = jnp.maximum(hi - lo, 1e-12)
-    # Clip in f32 BEFORE the int cast: out-of-range values (padding rows)
-    # would otherwise overflow the cast.
-    bins = jnp.clip((v - lo) / span * n_bins,
-                    0.0, n_bins - 1).astype(jnp.int32)        # [1, B]
-    bids = jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0)
-    wbin = (bins == bids).astype(jnp.float32) * w             # [NB, B]
-    hist_ref[...] += jax.lax.dot_general(                     # [NB, GB]
-        wbin, onehot, (((1,), (0,)), ((), ())),
-        precision=_EXACT, preferred_element_type=jnp.float32)
+        # Weighted value histogram over the family-global [lo, hi] range,
+        # reduced by the SAME resident onehot: wbin[NB, B] @ onehot[B, GB].
+        span = jnp.maximum(hi - lo, 1e-12)
+        # Clip in f32 BEFORE the int cast: out-of-range values (padding
+        # rows) would otherwise overflow the cast.
+        bins = jnp.clip((v - lo) / span * n_bins,
+                        0.0, n_bins - 1).astype(jnp.int32)    # [1, B]
+        bids = jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0)
+        wbin = (bins == bids).astype(jnp.float32) * w         # [NB, B]
+        hist_ref[...] += jax.lax.dot_general(                 # [NB, GB]
+            wbin, onehot, (((1,), (0,)), ((), ())),
+            precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("ops_struct", "atom_slots",
@@ -575,7 +634,8 @@ def quantile_scan_pallas(values: jax.Array, unit: jax.Array, strat: jax.Array,
                          n_bins: int = DEFAULT_QUANTILE_BINS,
                          block_rows: int = DEFAULT_BLOCK_ROWS_BATCHED,
                          block_groups: int = DEFAULT_BLOCK_GROUPS,
-                         interpret: bool = False
+                         interpret: bool = False,
+                         row_spans: jax.Array | None = None
                          ) -> tuple[jax.Array, jax.Array]:
     """One-pass QUANTILE scan: (moments f32[7, G], hist f32[n_bins, G]).
 
@@ -584,6 +644,7 @@ def quantile_scan_pallas(values: jax.Array, unit: jax.Array, strat: jax.Array,
     histogram over the fixed [lo, hi] range (pre-computed family-global
     bounds), bucketed as floor((v-lo)/span·n_bins) clipped to [0, n_bins).
     qconst lanes: 0 = k, 1 = lo, 2 = hi, 3..2+n_atoms = predicate consts.
+    `row_spans` bounds the row grid as in agg_scan_fused_pallas.
     """
     n = values.shape[0]
     n_atoms = sum(len(c) for c in ops_struct)
@@ -617,27 +678,19 @@ def quantile_scan_pallas(values: jax.Array, unit: jax.Array, strat: jax.Array,
             pred_consts.astype(jnp.float32).reshape(-1))
 
     n_row_blocks = n_pad // block_rows
-    n_group_blocks = g_pad // bg
-    row_spec = pl.BlockSpec((block_rows,), lambda gi, ri: (ri,))
-
+    plan = (jnp.full((3,), n_row_blocks, jnp.int32) if row_spans is None
+            else visit_plan(row_spans, n_row_blocks, block_rows))
     mom, hist = pl.pallas_call(
         functools.partial(_fused_quantile_kernel, block_groups=bg,
                           ops_struct=ops_struct, atom_slots=atom_slots,
                           n_bins=n_bins),
-        grid=(n_group_blocks, n_row_blocks),
-        in_specs=[
-            pl.BlockSpec((8, CONST_LANES), lambda gi, ri: (0, 0)),
-            pl.BlockSpec(ftab.shape, lambda gi, ri: (0, 0)),
-            row_spec, row_spec, row_spec, row_spec, row_spec,
-        ] + [row_spec] * len(acols),
-        out_specs=[
-            pl.BlockSpec((N_STATS, bg), lambda gi, ri: (0, gi)),
-            pl.BlockSpec((n_bins, bg), lambda gi, ri: (0, gi)),
-        ],
+        grid_spec=_bounded_grid(g_pad // bg, n_row_blocks, block_rows,
+                                [qconst.shape, ftab.shape], 5 + len(acols),
+                                [(N_STATS, bg), (n_bins, bg)]),
         out_shape=[
             jax.ShapeDtypeStruct((N_STATS, g_pad), jnp.float32),
             jax.ShapeDtypeStruct((n_bins, g_pad), jnp.float32),
         ],
         interpret=interpret,
-    )(qconst, ftab, v, u, s, va, c, *acols)
+    )(plan, qconst, ftab, v, u, s, va, c, *acols)
     return mom[:7, :n_groups], hist[:, :n_groups]

@@ -10,6 +10,8 @@ kernel), the fused quantile
 kernel, the grouped_quantile empty-selection guard, and the engine's
 one-pass QUANTILE execution (observable through its program caches).
 """
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,7 +26,7 @@ from repro.kernels import ref
 from repro.kernels.agg_scan import (CONST_LANES, MAX_FUSED_STRATA,
                                     agg_scan_batched_pallas,
                                     agg_scan_fused_pallas,
-                                    quantile_scan_pallas)
+                                    quantile_scan_pallas, visit_plan)
 
 
 def _fused_case(rng, n, n_groups, q, n_atoms, n_strata=37,
@@ -379,3 +381,128 @@ def test_engine_quantile_pallas_matches_jnp_roughly():
     col = np.asarray(tbl.columns["SessionTime"])
     span = float(col.max() - col.min())
     assert abs(answers[True] - answers[False]) <= span / 64
+
+
+# ------------------------------------------ row grid bounded by the key prefix
+
+BOUND_BLOCK = 256   # kernel row block in these cases (the engine's is 1024)
+
+
+def _sorted_case(n, n_groups, n_strata):
+    """A family block in entry-key order, keys unit·freq all distinct."""
+    rng = np.random.default_rng(n_groups * 31 + n_strata)
+    strat = rng.integers(0, n_strata, n).astype(
+        np.int8 if n_strata <= 127 else np.int16)
+    ftab = rng.integers(50, 5000, n_strata).astype(np.float32)
+    unit = rng.random(n).astype(np.float32)
+    keys = unit * ftab[strat]
+    order = np.argsort(keys, kind="stable")
+    unit, strat, keys = unit[order], strat[order], keys[order]
+    keep = np.concatenate([[True], np.diff(keys) > 0])
+    unit, strat, keys = unit[keep], strat[keep], keys[keep]
+    n = keys.size
+    values = rng.normal(5, 2, n).astype(np.float32)
+    codes = rng.integers(0, n_groups, n).astype(np.int16)
+    atom = rng.integers(0, 4, n).astype(np.int8)
+    return values, unit, strat, ftab, codes, atom, keys
+
+
+def _k_for(case, keys):
+    """The case's resolution cap, chosen against the stored keys."""
+    up = lambda x: np.nextafter(np.float32(x), np.float32(np.inf))    # noqa: E731
+    down = lambda x: np.nextafter(np.float32(x), np.float32(-np.inf))  # noqa: E731
+    return {"prefix0": keys[0], "prefix1": keys[1],
+            "block": keys[BOUND_BLOCK], "block+1": keys[BOUND_BLOCK + 1],
+            "at_key": keys[700], "ulp_below": down(keys[700]),
+            "ulp_above": up(keys[700]), "top": 2 * keys[-1],
+            "q2": keys[900], "groups200": keys[1300],
+            "strata15000": keys[500]}[case]
+
+
+@pytest.mark.parametrize("kernel", ["fused", "quantile"])
+@pytest.mark.parametrize("case", ["prefix0", "prefix1", "block", "block+1",
+                                  "at_key", "ulp_below", "ulp_above", "top",
+                                  "q2", "groups200", "strata15000"])
+def test_bounded_row_grid_is_bit_identical(kernel, case):
+    """A scan bounded by row_spans at the batch's largest k returns, bit
+    for bit, what the full grid returns, and matches kernels/ref.py. Rows
+    past the visited blocks are poisoned with NaN values in the bounded
+    run: they are never read, or the output would not be finite."""
+    n_groups = 200 if case == "groups200" else 7
+    n_strata = 15000 if case == "strata15000" else 40
+    v, u, s, ftab, c, atom, keys = _sorted_case(3000, n_groups, n_strata)
+    n = keys.size
+    k = np.float32(_k_for(case, keys))
+    ks = np.array([k, keys[300]] if case == "q2" else [k], np.float32)
+    if kernel == "quantile":
+        ks = ks[:1]
+    spans = exec_lib.row_spans(types.SimpleNamespace(
+        key_floor=keys, n_sorted=n, n_rows=n, n_shards=1), float(ks.max()))
+    below = int(np.sum(keys < ks.max()))
+    assert spans[0] >= below and spans[1] == spans[2] == spans[0]
+    steps = int(visit_plan(spans, -(-n // BOUND_BLOCK), BOUND_BLOCK,
+                           xp=np)[2])
+    assert steps == -(-below // BOUND_BLOCK)
+    poisoned = v.copy()
+    poisoned[steps * BOUND_BLOCK:] = np.nan
+    va = np.ones(n, bool)
+    struct = ((CmpOp.LE,),)
+    consts = np.full((ks.size, 1), 2.0, np.float32)
+    common = (u, s, ftab, va, (atom,), c)
+    kw = dict(ops_struct=struct, n_groups=n_groups, block_rows=BOUND_BLOCK,
+              interpret=True)
+    if kernel == "fused":
+        full = agg_scan_fused_pallas(v, *common, ks, consts, **kw)
+        got = agg_scan_fused_pallas(poisoned, *common, ks, consts,
+                                    row_spans=spans, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(full))
+        want = ref.agg_scan_fused_ref(v, *common, ks, consts,
+                                      ops_struct=struct, n_groups=n_groups)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=1e-3)
+        return
+    lo, hi = float(v.min()), float(v.max())
+    qargs = (ks[0], np.float32(lo), np.float32(hi), consts[0])
+    full = quantile_scan_pallas(v, *common, *qargs, **kw)
+    got = quantile_scan_pallas(poisoned, *common, *qargs, row_spans=spans,
+                               **kw)
+    for a, b in zip(got, full):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    mom_want = ref.agg_scan_fused_ref(v, *common, ks, consts,
+                                      ops_struct=struct, n_groups=n_groups)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(mom_want[0]),
+                               rtol=2e-5, atol=1e-3)
+    freq = ftab[s.astype(np.int64)]
+    w = ((u * freq < ks[0]) & (atom <= 2.0)) / np.minimum(1.0, ks[0] / freq)
+    hist = ref.quantile_hist_ref(jnp.asarray(v), jnp.asarray(w, jnp.float32),
+                                 jnp.asarray(c, jnp.int32), n_groups, lo, hi,
+                                 256)
+    np.testing.assert_allclose(np.asarray(got[1]), np.asarray(hist).T,
+                               rtol=2e-5, atol=1e-3)
+
+
+@pytest.mark.parametrize("n_shards", [1, 2, 4])
+def test_row_spans_cover_every_slot_a_scan_can_pass(n_shards):
+    """Every slot whose key passes `key < float32(k)` lies in the spans'
+    local rows (slot j at local row j // S), key-ordered head and
+    appended tail alike; the head holds no more than one local row past
+    the last passing head slot."""
+    rng = np.random.default_rng(n_shards)
+    n_sorted, n_rows = 5000, 5600
+    head = np.sort(rng.random(n_sorted).astype(np.float32) * 1000)
+    keys = np.concatenate([head, rng.random(n_rows - n_sorted).astype(
+        np.float32) * 1000])
+    striped = types.SimpleNamespace(key_floor=head, n_sorted=n_sorted,
+                                    n_rows=n_rows, n_shards=n_shards)
+    local = np.arange(n_rows) // n_shards
+    for k in [0.0, float(head[0]), float(head[1234]), 250.5, 999.0, 1e4]:
+        spans = exec_lib.row_spans(striped, k)
+        passing = local[keys < np.float32(k)]
+        inside = (passing < spans[0]) | ((passing >= spans[1])
+                                         & (passing < spans[2]))
+        assert inside.all(), (k, spans)
+        last = np.flatnonzero(head < np.float32(k))
+        assert spans[0] == (0 if last.size == 0
+                            else -(-(last[-1] + 1) // n_shards))
+        assert (spans[1], spans[2]) == (n_sorted // n_shards,
+                                        -(-n_rows // n_shards))

@@ -23,11 +23,13 @@ tier-1 regardless.
 """
 import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import (AggOp, Atom, BlinkDB, CmpOp, EngineConfig, ErrorBound,
                         Predicate, Query, QueryTemplate)
+from repro.core import executor as exec_lib
 from repro.core import sampling as samp
 from repro.core import table as table_lib
 from repro.core.maintenance import MaintenanceConfig, SampleMaintainer
@@ -866,3 +868,49 @@ def test_exact_query_excludes_tombstones_and_keeps_programs():
         assert after[key] == before[key]
     ans = db.exact_query(q)
     assert ans.rows_total == db.tables["s"].n_live
+
+
+# ------------------------------------- the kernel's row grid after mutations
+
+@pytest.mark.parametrize("mutation", ["tombstone", "append"])
+def test_bounded_scan_after_mutation_equals_fresh_restripe(mutation):
+    """The fused kernel visits only the row blocks a small K needs. After a
+    tombstone (dead slots stay in the block, so the live rows below K reach
+    further than the family's live count) and after an append into the
+    block's headroom (new slots sit past the key-ordered head, out of key
+    order), a scan at the family's smallest K must return what it returns
+    on a freshly restriped block: the same rows (counts equal exactly) and
+    the same sums. A bound taken from the family's live-row count, or one
+    that skips the appended tail, misses rows in each case."""
+    tbl = table_lib.from_columns("s", synth.sessions_table(40_000, seed=5))
+    db = BlinkDB(EngineConfig(k1=1000.0, m=3, seed=1, use_pallas=True))
+    db.register_table("s", tbl)
+    db.add_family("s", ())
+    q = Query("s", AggOp.SUM, "SessionTime", group_by=("OS",))
+    db._run_at_k("s", q, (), db.families["s"][()].ks[0])   # stripe, compile
+    if mutation == "tombstone":
+        for day in range(15):
+            db.delete_rows("s", Predicate.where(Atom("dt", CmpOp.EQ, day)))
+        striped = db._striped[("s", ())]
+        k = min(db.families["s"][()].ks)
+        dead = striped.n_ghosts
+        assert dead > 5000 and striped.n_sorted == striped.n_rows
+    else:
+        assert not db.append_rows("s", synth.sessions_table(
+            4000, seed=6)).restriped
+        striped = db._striped[("s", ())]
+        k = min(db.families["s"][()].ks)
+        tail = db.families["s"][()].entry_key_host.size - striped.n_sorted
+        assert striped.n_sorted < striped.n_rows and tail > 1000
+    fam = db.families["s"][()]
+    spans = exec_lib.row_spans(striped, k)
+    # the bound is a small share of the block, and more than the live count
+    assert exec_lib.rows_visited(striped, spans, True) < striped.n_rows // 2
+    assert spans[0] > fam.prefix_for_k(k) or spans[2] > spans[1]
+    got, rows, _, _ = db._run_at_k("s", q, (), k)
+    assert db.compact_family("s", ())
+    want, rows_fresh, _, _ = db._run_at_k("s", q, (), k)
+    assert rows == rows_fresh == fam.prefix_for_k(k)
+    np.testing.assert_array_equal(np.asarray(got.n), np.asarray(want.n))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)

@@ -604,6 +604,64 @@ def test_scan_split_names_the_family_strata(path, db, sub_db):
         engine.metrics.counter("engine_rows_read_total").value() > 0
 
 
+@pytest.mark.parametrize("path", ["single", "batched", "subsampled",
+                                  "quantile", "exact", "sharded"])
+def test_scan_launch_carries_the_rows_its_grid_visits(path, db, sub_db):
+    """Every `scan.launch` carries `rows_visited`, and
+    `engine_rows_visited_total` rises by their sum. The jnp path visits the
+    family's whole striped block; the exact program the table's rows."""
+    engine = sub_db if path == "subsampled" else db
+    visited = engine.metrics.counter("engine_rows_visited_total")
+    before = visited.value()
+    tr = _run_path(path, db, sub_db)
+    launches = tr.find("scan.launch")
+    assert launches
+    blocks = ({engine.tables["sessions"].n_rows} if path == "exact" else
+              {engine._striped_for("sessions", phi).capacity
+               for phi in engine.families["sessions"]})
+    for s in launches:
+        assert s.attrs["rows_visited"] in blocks, s.attrs
+    assert visited.value() - before == \
+        sum(s.attrs["rows_visited"] for s in launches)
+
+
+@pytest.fixture(scope="module")
+def kernel_db():
+    tbl = table_lib.from_columns("sessions",
+                                 synth.sessions_table(60_000, seed=2))
+    d = BlinkDB(EngineConfig(k1=400.0, m=3, seed=1, use_pallas=True))
+    d.register_table("sessions", tbl)
+    d.add_family("sessions", ())
+    return d
+
+
+def test_kernel_scans_visit_the_prefix_of_their_k(kernel_db):
+    """On the fused kernel a pilot at the family's smallest K visits the
+    row blocks of its key prefix, fewer than the family's rows; a scan at
+    the top K visits the whole family, rounded up to a row block."""
+    from repro.kernels.agg_scan import DEFAULT_BLOCK_ROWS_BATCHED as block
+    fam = kernel_db.families["sessions"][()]
+    visited = kernel_db.metrics.counter("engine_rows_visited_total")
+    before = visited.value()
+    pilot = _traced(lambda: kernel_db.query(_q(
+        kernel_db, "SELECT AVG(SessionTime) FROM sessions "
+        "ERROR WITHIN 10% CONFIDENCE 95%")))
+    top = _traced(lambda: kernel_db.query(_q(
+        kernel_db, "SELECT AVG(SessionTime) FROM sessions")))
+    probes = [s for s in pilot.find("scan") if s.attrs["k"] == min(fam.ks)]
+    assert probes
+    for probe in probes:
+        (launch,) = [s for s in pilot.find("scan.launch")
+                     if s.parent == probe.index]
+        assert probe.attrs["rows_read"] <= launch.attrs["rows_visited"] \
+            < fam.n_rows
+    (full,) = top.find("scan.launch")
+    assert full.attrs["rows_visited"] == -(-fam.n_rows // block) * block
+    assert visited.value() - before == sum(
+        s.attrs["rows_visited"]
+        for tr in (pilot, top) for s in tr.find("scan.launch"))
+
+
 def test_new_spans_keep_the_stage_readings(db):
     """The rule for new spans: a span of a stage Answer.timings and the
     benchmark's readers bill (plan, scan, estimate) either existed before

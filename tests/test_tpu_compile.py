@@ -125,3 +125,34 @@ def test_batched_executor_program_compiles_for_v5e(one_chip, compile_v5e,
                        cols, s((1, n), jnp.float32), s((1, n), jnp.int16),
                        s((256,), jnp.float32), s((1, n), jnp.bool_))
     assert "tpu_custom_call" in text
+
+
+def _served_tail(s, n):
+    cols = {"City": s((1, n), jnp.int16), "OS": s((1, n), jnp.int8),
+            "Genre": s((1, n), jnp.int8),
+            "SessionTime": s((1, n), jnp.float32)}
+    return (cols, s((1, n), jnp.float32), s((1, n), jnp.int16),
+            s((15104,), jnp.float32), s((1, n), jnp.bool_),
+            s((3,), jnp.int32))
+
+
+@pytest.mark.parametrize("builder", ["fused", "quantile"])
+def test_bounded_grid_compiles_for_v5e_under_the_served_vmap(
+        one_chip, compile_v5e, monkeypatch, builder):
+    """The engine's single-query and QUANTILE programs with their row grid
+    bounded by a traced `spans` argument: the scalar-prefetched bound stays
+    unbatched under the vmap over shards, and Mosaic compiles the kernel."""
+    monkeypatch.setattr(kops, "INTERPRET", False)
+    s = lambda shape, dt: _shape(one_chip, shape, dt)   # noqa: E731
+    struct = ((("OS", CmpOp.EQ), ("Genre", CmpOp.EQ)),)
+    vals = ((s((), jnp.float32), s((), jnp.float32)),)
+    if builder == "fused":
+        fn = exec_lib.make_query_fn(struct, "SessionTime", "City", 200,
+                                    use_pallas=True)
+        head = (s((), jnp.float32), vals)
+    else:
+        fn = exec_lib.make_quantile_fn(struct, "SessionTime", "City", 200,
+                                       use_pallas=True)
+        head = (s((), jnp.float32), vals) + (s((), jnp.float32),) * 3
+    text = compile_v5e(fn, *head, *_served_tail(s, N_ROWS))
+    assert "tpu_custom_call" in text
